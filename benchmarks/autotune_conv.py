@@ -46,8 +46,8 @@ SHAPES = [
     ("darknet_3x3_pool", 2, 28, 28, 32, 64,  3,  1,      1,   2),
     ("downsample_3x3_s2", 2, 28, 28, 64, 128, 3,  2,      1,   None),
     ("pointwise_1x1",  2, 14, 14, 128, 128,  1,  1,      0,   None),
-    # KWS dilated conv1d: dilation only moves the element-offset index map
-    # (it is free), so one undilated (3, 1) sweep covers the whole ladder.
+    # KWS dilated conv1d: dilation only moves the static tap offsets and
+    # adds halo rows, so one undilated (3, 1) sweep covers the ladder.
     ("kws_3x1_s1",     2, 138, 1, 45,  45, (3, 1), 1,    0,   None),
 ]
 

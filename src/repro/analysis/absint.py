@@ -4,7 +4,7 @@ This is the machinery behind intlint. Each jaxpr variable is mapped to an
 :class:`AbsVal` — a scalar interval ``[lo, hi]`` that bounds *every element*
 of the array, plus a ``tainted`` bit marking data derived from quantized
 integer codes. The interpreter walks the jaxpr equation by equation,
-recursing into ``pjit`` / ``cond`` / ``pallas_call`` sub-jaxprs, and calls
+recursing into ``jit`` / ``cond`` / ``pallas_call`` sub-jaxprs, and calls
 back into a :class:`Checker` at each equation so passes can flag violations
 (float ops on tainted data, accumulator overflow, narrow accumulation).
 
@@ -33,7 +33,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-from jax import core as jcore  # noqa: F401  (kept for forward-compat)
+from jax.extend import core as jex_core
 
 INF = float("inf")
 
@@ -211,7 +211,7 @@ class Interp:
     def __init__(self, checker: Optional[Checker] = None):
         self.checker = checker or Checker()
         # context stack of (kind, name) for finding subjects, e.g.
-        # [("pjit", "int_core"), ("pallas", "fq_conv2d_kernel")]
+        # [("jit", "int_core"), ("pallas", "fq_conv2d_kernel")]
         self.context: List[Tuple[str, str]] = []
         # grid axis -> AbsVal for program_id inside a pallas kernel body
         self.grid_env: Dict[int, AbsVal] = {}
@@ -226,7 +226,7 @@ class Interp:
 
     @staticmethod
     def _read(env, v):
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jex_core.Literal):
             return abs_of_concrete(v.val)
         return env[v]
 
@@ -286,10 +286,10 @@ class Interp:
                       for c in closed.consts]
         return self.run_jaxpr(closed.jaxpr, const_vals, ins)
 
-    def _pjit(self, eqn, ins) -> List:
+    def _jit(self, eqn, ins) -> List:
         closed = eqn.params["jaxpr"]
-        nm = str(eqn.params.get("name", "pjit"))
-        self.context.append(("pjit", nm))
+        nm = str(eqn.params.get("name", "jit"))
+        self.context.append(("jit", nm))
         try:
             return self._call_closed(closed, ins)
         finally:
@@ -908,7 +908,7 @@ _TRANSFER: Dict[str, Callable] = {
     "get": _get, "swap": _swap, "addupdate": _addupdate,
     "program_id": _program_id, "num_programs": _num_programs,
     # higher-order
-    "pjit": Interp._pjit, "cond": Interp._cond, "while": Interp._while,
+    "jit": Interp._jit, "cond": Interp._cond, "while": Interp._while,
     "scan": Interp._scan, "pallas_call": Interp._pallas_call,
     "custom_jvp_call": lambda i, e, ins: i._call_closed(
         e.params["call_jaxpr"], ins),

@@ -30,6 +30,7 @@ from typing import Callable, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax.extend import core as jex_core
 
 from . import absint
 from .absint import AbsVal, AnalysisIncomplete, Checker, Interp
@@ -92,7 +93,7 @@ class IntLintChecker(Checker):
     # -- purity ------------------------------------------------------------
 
     _HIGHER_ORDER = frozenset({
-        "pjit", "cond", "while", "scan", "pallas_call", "custom_jvp_call",
+        "jit", "cond", "while", "scan", "pallas_call", "custom_jvp_call",
         "custom_vjp_call", "custom_vjp_call_jaxpr", "closed_call", "remat",
     })
 
@@ -108,7 +109,7 @@ class IntLintChecker(Checker):
                         if hasattr(v, "aval"))
         in_float = any(_is_float_dtype(v.aval) for v in eqn.invars
                        if hasattr(v, "aval") and not isinstance(
-                           v, jax.core.Literal))
+                           v, jex_core.Literal))
         if not (out_float or in_float):
             # pure integer op on codes: always fine (purity-wise)
             if name == "dot_general":

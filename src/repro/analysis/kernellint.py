@@ -9,8 +9,10 @@ crash (or a silent fallback). Three layers of checking:
   not;
 * **per-shape** — every conv geometry a stack actually serves is pushed
   through ``pick_blocks`` and the resulting (bho, bco, bc) is checked
-  for grid divisibility (bc | cin, pool-aligned bho, positive grid) and
-  static VMEM footprint against the per-backend budget;
+  for grid divisibility (bc | cin, pool-aligned bho, positive grid), for
+  block widths Mosaic accepts (a channel block is the whole extent or a
+  multiple of 128 lanes) and for static VMEM footprint against the
+  per-backend budget;
 * **coverage** — served shape keys without a *measured* entry for the
   active backend are counted as structured misses (mirroring
   ``fq_conv.AutotuneMissWarning`` at serve time).
@@ -57,6 +59,7 @@ class ConvShape:
     stride: Tuple[int, int] = (1, 1)
     pool: Optional[Tuple[int, int]] = None
     weight_format: str = "int8"
+    dilation: Tuple[int, int] = (1, 1)
 
     @property
     def key(self) -> Tuple[int, int, int, str]:
@@ -208,7 +211,7 @@ def lint_shapes(shapes: Sequence[ConvShape], report: Report, *,
         try:
             bho, bco, bc = fq_conv.pick_blocks(
                 ho=s.ho, wo=s.wo, cin=s.cin, cout=s.cout, kh=s.kh,
-                kw=s.kw, stride=s.stride, pool=s.pool,
+                kw=s.kw, stride=s.stride, pool=s.pool, dilation=s.dilation,
                 bho=over.get("bho"), bco=over.get("bco"), bc=over_bc,
                 weight_format=s.weight_format)
         except ValueError as e:
@@ -236,15 +239,27 @@ def lint_shapes(shapes: Sequence[ConvShape], report: Report, *,
             clean = False
             report.error("kernellint/blockspec", sub,
                          f"non-positive block ({bho}, {bco}, {bc})")
-        n_red = s.kh * s.kw * (cin_eff // max(bc, 1))
-        grid = (math.ceil(s.ho / bho) * 1, math.ceil(s.cout / bco), n_red)
+        # Mosaic's block rule: a minor block dim is the array's whole
+        # extent or a multiple of the 128-lane tile (interpret-mode
+        # backends take any width, and never load a TPU table)
+        for knob, blk, full in (("bc", bc, cin_eff), ("bco", bco, s.cout)):
+            if backend == "tpu" and blk != full and blk % fq_conv._LANES:
+                clean = False
+                report.error(
+                    "kernellint/blockspec", sub,
+                    f"{knob}={blk} is neither the whole extent {full} nor "
+                    f"a multiple of {fq_conv._LANES} lanes — Mosaic "
+                    "refuses the block", knob=knob, block=blk, extent=full)
+        grid = (math.ceil(s.ho / bho), math.ceil(s.cout / bco),
+                cin_eff // max(bc, 1))
         if any(g < 1 for g in grid):
             clean = False
             report.error("kernellint/blockspec", sub,
                          f"degenerate grid {grid}", grid=grid)
 
         vmem = fq_conv.vmem_footprint(bho=bho, wo=s.wo, bco=bco, bc=bc,
-                                      stride=s.stride,
+                                      kh=s.kh, kw=s.kw, stride=s.stride,
+                                      dilation=s.dilation, pool=s.pool,
                                       weight_format=s.weight_format)
         report.count("kernellint/shapes-checked")
         if vmem > budget:
@@ -272,7 +287,8 @@ def lint_shapes(shapes: Sequence[ConvShape], report: Report, *,
     if clean and shapes:
         report.prove(
             "kernellint/blockspec", f"{len(shapes)} served shapes",
-            f"block picks divide their grids and fit the {backend} VMEM "
+            f"block picks divide their grids, are lane-legal and fit "
+            f"the {backend} VMEM "
             f"lint budget ({budget / 2**20:.1f} MiB)",
             shapes=len(shapes))
 

@@ -106,7 +106,8 @@ def kws_conv_shapes(cfg, batch: int = 1,
         t_out = t - dil * (cfg.ksize - 1)
         shapes.append(ConvShape(
             name=f"kws/{name}", ho=t_out, wo=1, cin=cin, cout=cfg.filters,
-            kh=cfg.ksize, kw=1, weight_format=weight_format))
+            kh=cfg.ksize, kw=1, dilation=(dil, 1),
+            weight_format=weight_format))
         t, cin = t_out, cfg.filters
     return shapes
 

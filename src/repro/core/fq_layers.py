@@ -76,6 +76,23 @@ def init_fq_conv1d(key, ksize: int, cin: int, cout: int, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
+# Matmul precision of the float edge layers around an integer stack. A
+# TPU's default for f32 is one bf16 pass, which moves entry codes across
+# bin edges (0.2% of DarkNet-19's on a v5e). "highest" is f32-accurate for
+# convs and dots alike. A dot-algorithm preset such as "BF16_BF16_F32_X6"
+# is not: it reaches dots only, and a conv lowers it to the one-pass
+# default.
+EDGE_PRECISION = "highest"
+
+
+def edge_precision():
+    """The matmul-precision scope of the float edge layers. Every path that
+    computes an edge layer enters it — the served ``int_entry``/``int_exit``
+    and the deployment-in-the-loop ``qat_apply`` alike — so retraining sees
+    the entry codes that serving produces."""
+    return jax.default_matmul_precision(EDGE_PRECISION)
+
+
 # ---------------------------------------------------------------------------
 # Activation-range calibration (PTQ-style, used at the FQ transition)
 # ---------------------------------------------------------------------------

@@ -151,7 +151,9 @@ def unit_normal_field(idx: jax.Array, seed: jax.Array,
     u_sum = jnp.zeros(idx.shape, jnp.float32)
     for k in range(_IH_DRAWS):
         h = hash_u32(base + jnp.uint32(((k + 1) * _GOLDEN) & 0xFFFFFFFF))
-        u_sum = u_sum + (h >> 8).astype(jnp.float32)
+        # 24-bit value: exact through int32, and Mosaic has no direct
+        # uint32 -> f32 cast
+        u_sum = u_sum + (h >> 8).astype(jnp.int32).astype(jnp.float32)
     return u_sum * jnp.float32(2.0 ** -24) - jnp.float32(_IH_DRAWS / 2)
 
 

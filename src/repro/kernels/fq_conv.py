@@ -2,10 +2,9 @@
 
 The im2col path (kernels/ops.py) materializes every input patch in HBM — a
 ``ksize**2 x`` blow-up of activation bytes that dominates the int8 memory
-roofline. This kernel never builds patches: the grid reduces over the
-``kh*kw`` kernel taps (times optional Cin blocks), each step gathering the
-input window it needs directly into VMEM via an *unblocked* (element-offset)
-BlockSpec, multiplying it against that tap's weight slice on the MXU, and
+roofline. This kernel never builds patches. Each grid step holds one row
+tile of the input in VMEM and runs every kernel tap over it as a static
+slice, multiplying it against that tap's weight slice on the MXU and
 accumulating int8 x int8 into an int32 VMEM scratch. The requantization
 "ADC" is the same fused epilogue as ``fq_matmul`` (shared code — bit-exact
 by construction), so codes never leave VMEM at higher precision.
@@ -17,22 +16,38 @@ Layout contract (matches the im2col path and ``integer_inference``):
   * output       (B, Ho, Wo, Cout) int8 codes (requant) or f32 (dequant);
                  with ``pool`` set, (B, Ho//ph, Wo//pw, Cout).
 
-Grid is (B * Ho/bho, Cout/bco, kh*kw*n_cin_blocks): the batch dimension is
-*folded* into the output-row axis (small serving batches B=1..4 otherwise
-burn a whole grid dimension on 1-4 steps), and the reduction is innermost
-("arbitrary" semantics) so each output tile's accumulator stays resident in
-VMEM for the whole tap x channel reduction. Stride is applied by slicing
-the gathered window *after* it lands in VMEM and dilation enters only the
-element-offset index map, i.e. it is free. Padding costs one edge-padded
-copy of the activations in HBM (jnp.pad before the kernel) — O(input
-bytes), not the O(ksize^2 * input) of im2col patches.
+Tiling (what Mosaic accepts: block shapes whose last two dims are the
+array's own, and sublane slices whose offsets are static):
+  * **Phases.** The output pixel the kernel writes steps through the input
+    by ``period = pool * stride`` per row and column. The wrapper splits
+    the edge-padded input into its ``period_h * period_w`` phase images
+    (input pixel (P*q + r) lands in phase r at q), so every tap of every
+    pool position reads a *contiguous* window of one phase image: stride
+    and the 2x2 pool become phase selection, never strided slicing.
+  * **Flat rows.** Each phase image is flattened with a row pitch ``wq``
+    (the output width plus the widest tap's column offset), so a tap's
+    window over ``bhf`` output rows is the flat slice
+    ``[qh*wq + qw, +bhf*wq)`` — one 2-D int8 matmul operand. Columns
+    ``wo_out..wq-1`` of each output row are computed and sliced away.
+  * **Row tiles.** Output rows are cut into tiles of ``bhf``; each tile's
+    input rows, halo included, are gathered once in HBM, so the x block is
+    the tile's whole (phases, rows*wq, bc) slab and all tap offsets inside
+    it are compile-time constants.
+
+Grid is (B * n_row_tiles, Cout/bco, Cin/bc): the batch dimension is
+*folded* into the row-tile axis (small serving batches B=1..4 otherwise
+burn a whole grid dimension on 1-4 steps), and the channel reduction is
+innermost ("arbitrary" semantics) so each output tile's accumulators stay
+resident in VMEM. The phase split and halo gather cost one rearranged
+copy of the activations in HBM — O(input bytes), not the
+O(ksize^2 * input) of im2col patches.
 
 Fused maxpool epilogue: FQ-Conv's learned quantizer is monotone, so
 requantization commutes with max (Q(max x) == max Q(x) — the same fact
 ``integer_inference.int_maxpool2d`` exploits on codes). With ``pool=(2,2)``
-the non-overlapping maxpool therefore runs on the *int32 accumulator tile*
-inside VMEM, before requant: a pooled layer writes Ho*Wo/4 output bytes to
-HBM instead of Ho*Wo plus a second full read+write pooling pass.
+the kernel keeps one int32 accumulator per pool position and takes their
+elementwise max before requant: a pooled layer writes Ho*Wo/4 output
+bytes to HBM instead of Ho*Wo plus a second full read+write pooling pass.
 
 Block sizes: explicit knobs win, then ``AUTOTUNE_TABLE`` — measured-sweep
 winners persisted by ``benchmarks/autotune_conv.py`` to the checked-in
@@ -48,15 +63,16 @@ import functools
 import json
 import os
 import warnings
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import quant
-from .fq_matmul import TPUCompilerParams, apply_epilogue, noise_tile
+from .fq_matmul import apply_epilogue, noise_tile
 
 # ---------------------------------------------------------------------------
 # Block-size selection
@@ -205,6 +221,7 @@ def _note_autotune_miss(key: Tuple[int, int, int, str]):
 
 
 _VMEM_BUDGET = 4 * 1024 * 1024  # conservative half-ish of usable VMEM
+_LANES = 128                       # TPU vector lane width
 
 
 def _divisor_at_most(n: int, cap: int) -> int:
@@ -214,43 +231,117 @@ def _divisor_at_most(n: int, cap: int) -> int:
     return 1
 
 
-def vmem_footprint(*, bho: int, wo: int, bco: int, bc: int,
-                   stride: Tuple[int, int],
+def _channel_block(n: int, cap: int) -> int:
+    """Default channel block: the whole extent when it fits ``cap``, else
+    the largest lane-multiple divisor of ``n`` under ``cap`` — the two
+    block widths Mosaic accepts for a minor dimension."""
+    if n <= cap:
+        return n
+    for d in range(cap - cap % _LANES, 0, -_LANES):
+        if n % d == 0:
+            return d
+    return n
+
+
+class Tiling(NamedTuple):
+    """Static geometry of one fused-conv call (see the module docstring)."""
+
+    period: Tuple[int, int]   # input rows/cols per output row/col (pool*stride)
+    pool: Tuple[int, int]     # (1, 1) when no pool is fused
+    bhf: int                  # output rows (post-pool) per row tile
+    wq: int                   # row pitch of the flattened phase images
+    ht: int                   # phase-image rows one tile reads, halo included
+
+    @property
+    def n_phase(self) -> int:
+        return self.period[0] * self.period[1]
+
+    @property
+    def n_pos(self) -> int:
+        return self.pool[0] * self.pool[1]
+
+    @property
+    def m(self) -> int:
+        return self.bhf * self.wq
+
+
+def tiling(*, bho: int, wo: int, kh: int, kw: int, stride: Tuple[int, int],
+           dilation: Tuple[int, int] = (1, 1),
+           pool: Optional[Tuple[int, int]] = None) -> Tiling:
+    """Row-tile geometry for ``bho`` pre-pool output rows per tile."""
+    pool = tuple(pool) if pool is not None else (1, 1)
+    period = (pool[0] * stride[0], pool[1] * stride[1])
+    # deepest tap offset, in whole phase rows/cols
+    qh = ((pool[0] - 1) * stride[0] + (kh - 1) * dilation[0]) // period[0]
+    qw = ((pool[1] - 1) * stride[1] + (kw - 1) * dilation[1]) // period[1]
+    bhf = bho // pool[0]
+    # a tap window starting qw columns into the flat tile runs qw elements
+    # into the row below the last one: one extra row covers it
+    return Tiling(period=period, pool=pool, bhf=bhf, wq=wo // pool[1] + qw,
+                  ht=bhf + qh + (1 if qw else 0))
+
+
+def _tap_reads(t: Tiling, kh: int, kw: int, stride, dilation):
+    """Per pool position, the static (tap, phase, flat offset) of each tap's
+    window inside a tile."""
+    reads = []
+    for a in range(t.pool[0]):
+        for b in range(t.pool[1]):
+            pos = []
+            for di in range(kh):
+                for dj in range(kw):
+                    oh = a * stride[0] + di * dilation[0]
+                    ow = b * stride[1] + dj * dilation[1]
+                    phase = (oh % t.period[0]) * t.period[1] + ow % t.period[1]
+                    off = (oh // t.period[0]) * t.wq + ow // t.period[1]
+                    pos.append((di * kw + dj, phase, off))
+            reads.append(tuple(pos))
+    return tuple(reads)
+
+
+def vmem_footprint(*, bho: int, wo: int, bco: int, bc: int, kh: int,
+                   kw: int, stride: Tuple[int, int],
+                   dilation: Tuple[int, int] = (1, 1),
+                   pool: Optional[Tuple[int, int]] = None,
                    weight_format: str = "int8") -> int:
-    """Static VMEM bytes of one grid step: int8 x-window + weight slice +
-    int32 accumulator scratch + the out tile (worst case f32). Shared
-    with repro.analysis.kernellint, which checks it against the
+    """Static VMEM bytes of one grid step, minor dims padded to 128 lanes:
+    double-buffered int8 input tile, weight block and out tile (worst case
+    f32), plus the int32 accumulator scratch (one per pool position).
+    Shared with repro.analysis.kernellint, which checks it against the
     per-backend budget so a bad autotune row is a lint error rather than
     a Mosaic OOM. Packed formats stream bc*bco/factor weight bytes but
-    also materialize the unpacked int8 tile before the MAC, so both
+    also materialize the unpacked int8 taps before the MAC, so both
     terms count."""
-    bhi = (bho - 1) * stride[0] + 1
-    bwi = (wo - 1) * stride[1] + 1
+    t = tiling(bho=bho, wo=wo, kh=kh, kw=kw, stride=stride,
+               dilation=dilation, pool=pool)
+    lanes = lambda n: -(-n // _LANES) * _LANES  # noqa: E731
     factor = quant.format_factor(weight_format)
-    x_b = bhi * bwi * bc          # int8 window
-    w_b = bc * bco                # int8 weight slice (unpacked)
-    if factor > 1:
-        w_b += bc * bco // factor  # plus the packed byte tile it came from
-    acc = 4 * bho * wo * bco      # int32 scratch
-    out = bho * wo * bco          # int8/f32 out tile (worst: 4x)
-    return x_b + w_b + acc + 4 * out
+    x_b = t.n_phase * t.ht * t.wq * lanes(bc)
+    w_b = kh * kw * (bc // factor) * lanes(bco)
+    unpacked = kh * kw * bc * lanes(bco) if factor > 1 else 0
+    out = 4 * t.m * lanes(bco)
+    acc = 4 * t.n_pos * t.m * lanes(bco)
+    return 2 * (x_b + w_b + out) + unpacked + acc
 
 
 def pick_blocks(*, ho: int, wo: int, cin: int, cout: int, kh: int, kw: int,
                 stride: Tuple[int, int], pool: Optional[Tuple[int, int]] = None,
+                dilation: Tuple[int, int] = (1, 1),
                 bho: Optional[int] = None, bco: Optional[int] = None,
                 bc: Optional[int] = None,
                 weight_format: str = "int8") -> Tuple[int, int, int]:
     """(bho, bco, bc): output-row / output-channel / input-channel blocks.
 
-    Explicit arguments win, then the autotune table, then a VMEM-budget
-    heuristic that shrinks bho until x-window + w + int32 accumulator fit.
-    An explicit ``bc`` must divide ``cin`` exactly (a non-divisor block
-    would read weight rows across a tap boundary); table/heuristic values
-    are rounded down to a divisor. With a fused ``pool``, bho is rounded
-    down to a multiple of the pool height so pool windows never straddle a
-    row-tile boundary (explicit values included — tiling is a performance
-    knob, never a semantics knob).
+    ``bho`` counts conv output rows before the fused pool. Explicit
+    arguments win, then the autotune table, then a VMEM-budget heuristic
+    that halves bho until the footprint fits. An explicit ``bc`` must
+    divide ``cin`` exactly (a non-divisor block would read weight rows
+    across a tap boundary); table values are rounded down to a divisor.
+    The default ``bc``/``bco`` are the whole extent or a multiple of 128
+    lanes, the block widths Mosaic accepts. With a fused ``pool``, bho is
+    rounded down to a multiple of the pool height so pool windows never
+    straddle a row-tile boundary (explicit values included — tiling is a
+    performance knob, never a semantics knob).
 
     Packed weight formats fix ``bc`` to cin rounded up to the pack
     factor: a partial-channel block would split weight rows mid-byte.
@@ -282,23 +373,22 @@ def pick_blocks(*, ho: int, wo: int, cin: int, cout: int, kh: int, kw: int,
         _note_autotune_miss(key)
     bco = bco or over.get("bco")
     bho = bho or over.get("bho")
-    if not packed:
-        bc = bc or over.get("bc")
-        bc = _divisor_at_most(cin, bc or 512)
+    if not packed and bc is None:
+        bc = over.get("bc")
+        bc = _divisor_at_most(cin, bc) if bc else _channel_block(cin, 512)
 
-    bco = min(bco or 128, cout)
+    bco = min(bco or _LANES, cout)
 
+    ph = pool[0] if pool is not None else 1
     if bho is None:
         bho = min(ho, 128)
-        while bho > 1 and vmem_footprint(
-                bho=bho, wo=wo, bco=bco, bc=bc, stride=stride,
+        while bho > ph and vmem_footprint(
+                bho=bho, wo=wo, bco=bco, bc=bc, kh=kh, kw=kw, stride=stride,
+                dilation=dilation, pool=pool,
                 weight_format=weight_format) > _VMEM_BUDGET:
             bho = (bho + 1) // 2
     bho = min(bho, ho)
-    if pool is not None:
-        ph = pool[0]
-        bho = max(ph, bho - bho % ph)
-    return bho, bco, bc
+    return max(ph, bho - bho % ph), bco, bc
 
 
 # ---------------------------------------------------------------------------
@@ -306,11 +396,10 @@ def pick_blocks(*, ho: int, wo: int, cin: int, cout: int, kh: int, kw: int,
 # ---------------------------------------------------------------------------
 
 
-def _kernel(scale_ref, x_ref, w_ref, *refs, n_red: int,
-            stride: Tuple[int, int], bho: int, wo: int,
-            pool: Optional[Tuple[int, int]], epilogue: str, n_out: int,
-            lo: int, noise: bool, mac_chunks: int, n_i: int, ho: int,
-            cout: int, weight_format: str):
+def _kernel(scale_ref, x_ref, w_ref, *refs, n_cb: int, reads, t: Tiling,
+            epilogue: str, n_out: int, lo: int, noise: bool,
+            mac_chunks: int, n_i: int, ho: int, wo: int, cout: int,
+            weight_format: str):
     if noise:
         sigma_ref, seed_ref, o_ref, acc_ref = refs
         # program_id reads hoisted out of the pl.when body (interpret
@@ -318,62 +407,66 @@ def _kernel(scale_ref, x_ref, w_ref, *refs, n_red: int,
         p, j = pl.program_id(0), pl.program_id(1)
     else:
         o_ref, acc_ref = refs
-    r = pl.program_id(2)
+    c = pl.program_id(2)
 
-    @pl.when(r == 0)
+    @pl.when(c == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # (bhi, bwi, bc) window -> strided view (bho, wo, bc) -> (bho*wo, bc).
-    v = x_ref[0][:: stride[0], :: stride[1], :]
-    w_tap = w_ref[...]
-    if weight_format != "int8":
-        # (bc/factor, bco) packed bytes -> (bc, bco) int8 codes in VMEM
-        # ahead of the MAC; accumulator math is the int8 kernel's.
-        w_tap = quant.unpack_codes(w_tap, weight_format)
-    acc_ref[...] += jnp.dot(
-        v.reshape(bho * wo, -1), w_tap,
-        preferred_element_type=jnp.int32,
-    )
+    taps = []
+    for tap in range(w_ref.shape[0]):
+        w_tap = w_ref[tap]
+        if weight_format != "int8":
+            # (bc/factor, bco) packed bytes -> (bc, bco) int8 codes in
+            # VMEM ahead of the MAC; accumulator math is the int8 kernel's.
+            w_tap = quant.unpack_codes(w_tap, weight_format)
+        taps.append(w_tap)
+    for k, pos in enumerate(reads):
+        part = None
+        for tap, phase, off in pos:
+            d = jnp.dot(x_ref[0, phase, off:off + t.m, :], taps[tap],
+                        preferred_element_type=jnp.int32)
+            part = d if part is None else part + d
+        acc_ref[k] += part
 
-    @pl.when(r == n_red - 1)
+    @pl.when(c == n_cb - 1)
     def _epilogue():
-        acc = acc_ref[...]
         if noise:
-            # ADC noise on the PRE-POOL int32 accumulator (paper §4.4),
+            # ADC noise on each PRE-POOL int32 accumulator (paper §4.4),
             # indexed by the global conv-output coordinate flattened the
-            # same way the im2col path flattens matmul rows: the tile's
-            # (bho*wo, bco) element (s, c) is global row (b*ho + rb*bho
-            # + s//wo)*wo + s%wo = (b*ho + rb*bho)*wo + s, column j*bco
-            # + c, over the TRUE (ho, cout) — independent of tiling — so
-            # fq_matmul's epilogue draws the identical field and the
-            # reference path is bit-for-bit reproducible. Rows/channels
-            # in grid padding draw values that are sliced away.
-            row0 = ((p // n_i) * ho + (p % n_i) * bho) * wo
-            acc = acc.astype(jnp.float32) + noise_tile(
-                acc.shape, row0, j * acc.shape[1], cout,
-                seed_ref[0, 0], sigma_ref[0, 0], mac_chunks)
-        if pool is not None:
-            # Code-domain maxpool hoisted onto the int32 accumulator (the
-            # noisy f32 accumulator when the noise epilogue ran — both
-            # f32 conversion and the requant epilogue are monotone
-            # non-decreasing, scale > 0, so max commutes either way):
-            # pooling here is bit-exact with int_maxpool2d over
-            # requantized codes, but never writes the unpooled tile to
-            # HBM. Strided-slice maxes (the same idiom as the conv's
-            # stride) keep Mosaic on 3-D tensors.
-            ph, pw = pool
-            a3 = acc.reshape(bho, wo, acc.shape[-1])
-            a3 = a3[:, : (wo // pw) * pw, :]
-            m = a3[:: ph, :: pw, :]
-            for di in range(ph):
-                for dj in range(pw):
-                    if di or dj:
-                        m = jnp.maximum(m, a3[di:: ph, dj:: pw, :])
-            acc = m.reshape((bho // ph) * (wo // pw), -1)
-        y = apply_epilogue(acc, scale_ref[0, 0],
-                           epilogue=epilogue, n_out=n_out, lo=lo)
-        o_ref[...] = y.reshape(o_ref.shape)
+            # same way the im2col path flattens matmul rows: tile element
+            # (il, jj) of pool position (a, b) is conv output
+            # (y, x) = (pool_h*(ti*bhf + il) + a, pool_w*jj + b) of image
+            # bb, row (bb*ho + y)*wo + x, column j*bco + c over the TRUE
+            # (ho, wo, cout) — independent of tiling — so fq_matmul's
+            # epilogue draws the identical field and the reference path
+            # is bit-for-bit reproducible. Elements in the pitch/grid
+            # padding draw values that are sliced away.
+            shape3 = (t.bhf, t.wq, acc_ref.shape[-1])
+            il = jax.lax.broadcasted_iota(jnp.int32, shape3, 0).reshape(
+                t.m, -1)
+            jj = jax.lax.broadcasted_iota(jnp.int32, shape3, 1).reshape(
+                t.m, -1)
+            y0 = t.pool[0] * ((p % n_i) * t.bhf + il)
+            img0 = (p // n_i) * ho
+        acc = None
+        for k in range(t.n_pos):
+            a_k = acc_ref[k]
+            if noise:
+                a, b = divmod(k, t.pool[1])
+                rows = (img0 + y0 + a) * wo + t.pool[1] * jj + b
+                a_k = a_k.astype(jnp.float32) + noise_tile(
+                    rows, j * a_k.shape[1], cout, seed_ref[0, 0],
+                    sigma_ref[0, 0], mac_chunks)
+            # Code-domain maxpool hoisted onto the accumulators (the noisy
+            # f32 ones when the noise epilogue ran — both f32 conversion
+            # and the requant epilogue are monotone non-decreasing,
+            # scale > 0, so max commutes either way): bit-exact with
+            # int_maxpool2d over requantized codes, but the unpooled tile
+            # never reaches HBM.
+            acc = a_k if acc is None else jnp.maximum(acc, a_k)
+        o_ref[0] = apply_epilogue(acc, scale_ref[0, 0], epilogue=epilogue,
+                                  n_out=n_out, lo=lo)
 
 
 @functools.partial(
@@ -420,7 +513,7 @@ def fq_conv2d(
 
     ``pool=(ph, pw)`` additionally fuses a non-overlapping VALID maxpool
     (window == stride, e.g. (2, 2)) into the epilogue: the pool runs on the
-    int32 accumulator before requant, so only the pooled tile reaches HBM.
+    int32 accumulators before requant, so only the pooled tile reaches HBM.
 
     ``noise_sigma_acc`` (std in ACCUMULATOR units, the caller folds the
     paper's sigma_mac through the requant scale) + ``noise_seed`` (uint32)
@@ -454,71 +547,60 @@ def fq_conv2d(
         assert kcin == kh * kw * cin, (w_codes.shape, (kh, kw, cin))
     sh, sw = stride
     dh, dw = dilation
-    ph, pw = padding
+    pad_h, pad_w = padding
 
-    hp, wp = h + 2 * ph, w + 2 * pw
     span_h, span_w = (kh - 1) * dh + 1, (kw - 1) * dw + 1
-    ho = (hp - span_h) // sh + 1
-    wo = (wp - span_w) // sw + 1
+    ho = (h + 2 * pad_h - span_h) // sh + 1
+    wo = (w + 2 * pad_w - span_w) // sw + 1
     assert ho > 0 and wo > 0, (a_codes.shape, (kh, kw), stride, dilation)
     if pool is not None:
-        pool_h, pool_w = pool
-        assert pool_h >= 1 and pool_w >= 1
-        assert ho >= pool_h and wo >= pool_w, \
+        assert pool[0] >= 1 and pool[1] >= 1
+        assert ho >= pool[0] and wo >= pool[1], \
             f"pool {pool} larger than conv output ({ho}, {wo})"
 
     bho, bco, bc = pick_blocks(ho=ho, wo=wo, cin=cin, cout=cout, kh=kh,
-                               kw=kw, stride=stride, pool=pool, bho=bho,
-                               bco=bco, bc=bc, weight_format=weight_format)
-    n_i = pl.cdiv(ho, bho)
+                               kw=kw, stride=stride, pool=pool,
+                               dilation=dilation, bho=bho, bco=bco, bc=bc,
+                               weight_format=weight_format)
+    t = tiling(bho=bho, wo=wo, kh=kh, kw=kw, stride=stride,
+               dilation=dilation, pool=pool)
+    h_out, w_out = ho // t.pool[0], wo // t.pool[1]
+    n_i = pl.cdiv(h_out, t.bhf)
     n_j = pl.cdiv(cout, bco)
     cout_pad = n_j * bco
     n_cb = cin // bc
-    n_red = kh * kw * n_cb
 
-    # Pad so every unblocked window read is in-bounds: the last row block
-    # reads up to (n_i*bho-1)*sh + span_h; the widest tap reads up to
-    # (kw-1)*dw + (wo-1)*sw + 1 columns. Only edge bytes — no ksize**2
-    # patch blow-up (the whole point).
-    need_h = (n_i * bho - 1) * sh + span_h
-    need_w = (kw - 1) * dw + (wo - 1) * sw + 1
-    a_codes = jnp.pad(a_codes, ((0, 0), (ph, max(need_h - hp, 0) + ph),
-                                (pw, max(need_w - wp, 0) + pw), (0, 0)))
+    # Edge-pad (or crop unread trailing pixels) to exactly the phase
+    # images' extent, split into phases, then gather each row tile's
+    # rows plus its halo: (B * n_i, phases, ht * wq, cin). Without a halo
+    # (1x1 taps, no pool) the tiles partition the rows: a reshape.
+    per_h, per_w = t.period
+    hq = (n_i - 1) * t.bhf + t.ht
+    x = jax.lax.pad(a_codes, jnp.zeros((), a_codes.dtype), (
+        (0, 0, 0), (pad_h, per_h * hq - h - pad_h, 0),
+        (pad_w, per_w * t.wq - w - pad_w, 0), (0, 0, 0)))
+    x = x.reshape(b, hq, per_h, t.wq, per_w, cin)
+    x = x.transpose(0, 2, 4, 1, 3, 5).reshape(b, t.n_phase, hq, t.wq, cin)
+    if n_i > 1 and t.ht == t.bhf:
+        x = jnp.moveaxis(x.reshape(b, t.n_phase, n_i, t.bhf, t.wq, cin), 2, 1)
+    elif n_i > 1:
+        rows = np.arange(n_i)[:, None] * t.bhf + np.arange(t.ht)[None, :]
+        x = jnp.moveaxis(x[:, :, rows], 2, 1)
+    x = x.reshape(b * n_i, t.n_phase, t.ht * t.wq, cin)
+
+    w_taps = w_codes.reshape(kh * kw, kcin // (kh * kw), cout)
     if cout_pad != cout:
-        w_codes = jnp.pad(w_codes, ((0, 0), (0, cout_pad - cout)))
+        w_taps = jnp.pad(w_taps, ((0, 0), (0, 0), (0, cout_pad - cout)))
 
-    bhi = (bho - 1) * sh + 1
-    bwi = (wo - 1) * sw + 1
-
-    # Batch folded into the leading (output-row) grid axis: index p is
-    # (batch, row-block) = (p // n_i, p % n_i). B=1..4 serving shapes fold
-    # into one axis instead of wasting a whole grid dimension.
-    def x_index(p, j, r):
-        t = r // n_cb
-        cb = r % n_cb
-        return (p // n_i, (p % n_i) * (bho * sh) + (t // kw) * dh,
-                (t % kw) * dw, cb * bc)
-
-    def w_index(p, j, r):
-        t = r // n_cb
-        cb = r % n_cb
-        # packed arrays hold factor codes per row; bc (== cin, padded) is
-        # a factor multiple and n_cb == 1, so this lands on a byte row
-        return ((t * cin + cb * bc) // factor, j * bco)
-
-    if pool is not None:
-        bho_out, wo_out = bho // pool_h, wo // pool_w
-    else:
-        bho_out, wo_out = bho, wo
-    scalar_spec = pl.BlockSpec((1, 1), lambda p, j, r: (0, 0))
+    scalar_spec = pl.BlockSpec((1, 1), lambda p, j, c: (0, 0))
     in_specs = [
-        scalar_spec,                                             # scale
-        pl.BlockSpec((1, bhi, bwi, bc), x_index,
-                     indexing_mode=pl.unblocked),                # window
-        pl.BlockSpec((bc // factor, bco), w_index,
-                     indexing_mode=pl.unblocked),                # tap w
+        scalar_spec,                                                 # scale
+        pl.BlockSpec((1, t.n_phase, t.ht * t.wq, bc),
+                     lambda p, j, c: (p, 0, 0, c)),                  # tile
+        pl.BlockSpec((kh * kw, bc // factor, bco),
+                     lambda p, j, c: (0, c, j)),                     # taps
     ]
-    inputs = [scale.reshape(1, 1).astype(jnp.float32), a_codes, w_codes]
+    inputs = [scale.reshape(1, 1).astype(jnp.float32), x, w_taps]
     if noise:
         in_specs += [scalar_spec, scalar_spec]                   # sigma, seed
         inputs += [jnp.asarray(noise_sigma_acc, jnp.float32).reshape(1, 1),
@@ -526,25 +608,24 @@ def fq_conv2d(
     out_dtype = jnp.int8 if epilogue == "requant" else jnp.float32
     out = pl.pallas_call(
         functools.partial(
-            _kernel, n_red=n_red, stride=stride, bho=bho, wo=wo, pool=pool,
-            epilogue=epilogue, n_out=n_out, lo=lo, noise=noise,
-            mac_chunks=mac_chunks, n_i=n_i, ho=ho, cout=cout,
+            _kernel, n_cb=n_cb, reads=_tap_reads(t, kh, kw, stride, dilation),
+            t=t, epilogue=epilogue, n_out=n_out, lo=lo, noise=noise,
+            mac_chunks=mac_chunks, n_i=n_i, ho=ho, wo=wo, cout=cout,
             weight_format=weight_format,
         ),
-        grid=(b * n_i, n_j, n_red),
+        grid=(b * n_i, n_j, n_cb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bho_out, wo_out, bco),
-                               lambda p, j, r: (p // n_i, p % n_i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n_i * bho_out, wo_out, cout_pad),
-                                       out_dtype),
-        scratch_shapes=[pltpu.VMEM((bho * wo, bco), jnp.int32)],
-        compiler_params=TPUCompilerParams(
+        out_specs=pl.BlockSpec((1, t.m, bco), lambda p, j, c: (p, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b * n_i, t.m, cout_pad), out_dtype),
+        scratch_shapes=[pltpu.VMEM((t.n_pos, t.m, bco), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fq_conv2d",
     )(*inputs)
-    ho_out = ho // pool_h if pool is not None else ho
-    return out[:, :ho_out, :, :cout]
+    out = out.reshape(b, n_i * t.bhf, t.wq, cout_pad)
+    return out[:, :h_out, :w_out, :cout]
 
 
 def fq_conv1d(

@@ -29,13 +29,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core import quant
 from ..core.noise import mac_noise_field
 
-# jax renamed TPUCompilerParams (<=0.4.x) to CompilerParams (>=0.5); resolve
-# whichever exists so neither pin breaks the suite.
-TPUCompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
 def apply_epilogue(acc, scale, *, epilogue: str, n_out: int, lo: int):
     """The fused requant/dequant 'ADC' epilogue on an int32 accumulator.
 
@@ -49,19 +42,17 @@ def apply_epilogue(acc, scale, *, epilogue: str, n_out: int, lo: int):
     return acc.astype(jnp.float32) * scale  # dequant
 
 
-def noise_tile(shape, row0, col0, n_cols: int, seed, sigma,
-               mac_chunks: int):
+def noise_tile(rows, col0, n_cols: int, seed, sigma, mac_chunks: int):
     """ADC-noise tile for a (rows, cols) accumulator block.
 
-    Indexed by the GLOBAL element position ``(row0 + i) * n_cols +
-    (col0 + j)`` with the TRUE (unpadded) column count, so the field is
-    independent of tiling/padding and the fused conv kernel — whose
-    im2col-flattened output coordinates are exactly these (row, col)
-    pairs — reproduces it bit-for-bit. Padded rows/cols draw values that
+    ``rows`` holds each element's GLOBAL output row (same shape as the
+    block). The field is indexed by ``row * n_cols + (col0 + j)`` with the
+    TRUE (unpadded) column count, so it is independent of tiling/padding
+    and the fused conv kernel — which passes its im2col-flattened output
+    rows — reproduces it bit-for-bit. Padded rows/cols draw values that
     the caller slices away.
     """
-    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
     return mac_noise_field(rows * n_cols + cols, seed, sigma,
                            chunks=mac_chunks)
 
@@ -100,9 +91,10 @@ def _kernel(scale_ref, a_ref, b_ref, *refs, k_steps: int,
             # element before the requant bins it — the analog-noise
             # story of paper §4.4 on the TPU epilogue.
             bm, bn = acc.shape
+            rows = i * bm + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
             acc = acc.astype(jnp.float32) + noise_tile(
-                acc.shape, i * bm, j * bn, n_true,
-                seed_ref[0, 0], sigma_ref[0, 0], mac_chunks)
+                rows, j * bn, n_true, seed_ref[0, 0], sigma_ref[0, 0],
+                mac_chunks)
         o_ref[...] = apply_epilogue(
             acc, scale_ref[0, 0], epilogue=epilogue, n_out=n_out, lo=lo)
 
@@ -205,9 +197,10 @@ def fq_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fq_matmul",
     )(*inputs)
     return out[:m, :n]

@@ -46,5 +46,6 @@ def quantize_codes(
         out_specs=pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pr, c), jnp.int8),
         interpret=interpret,
+        name="quantize_codes",
     )(inv_scale.reshape(1, 1).astype(jnp.float32), x)
     return out[:r]

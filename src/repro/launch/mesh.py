@@ -17,14 +17,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: ``axis_types`` (and
-    jax.sharding.AxisType) only exist on jax >= 0.5; 0.4.x meshes are
-    implicitly all-Auto, which is what we want everywhere."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (GSPMD-propagated sharding)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

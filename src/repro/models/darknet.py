@@ -228,26 +228,50 @@ def int_apply(ip, x, qcfg: QuantConfig, cfg: DarkNetConfig, *, impl=None,
     The FP first/last convs stay clean per the deployment protocol —
     they never leave the digital domain.
     """
+    codes = int_entry(ip, x, qcfg, cfg)
+    codes = int_core(ip, codes, qcfg, cfg, impl=impl, fuse_pool=fuse_pool,
+                     noise=noise, rng=rng, mac_chunks=mac_chunks)
+    return int_exit(ip, codes, qcfg)
+
+
+def _fp_first_conv(p, x, qcfg: QuantConfig):
+    """FP first conv (BN folded into w), in the same fp-in-fq-mode config
+    as apply(), at the edge precision (``fq_layers.edge_precision``)."""
+    with fql.edge_precision():
+        return fql.fq_conv2d(p, x, QuantConfig(fq=qcfg.fq), padding="SAME",
+                             b_in=WEIGHT_BOUND)
+
+
+def _fp_head(p, h):
+    """FP classifier conv at the edge precision, then global average pool."""
+    with fql.edge_precision():
+        h = fql.fq_conv2d(p, h, QuantConfig(), padding="SAME",
+                          b_in=RELU_BOUND)
+    return jnp.mean(h, axis=(1, 2))
+
+
+def int_entry(ip, x, qcfg: QuantConfig, cfg: DarkNetConfig):
+    """The float prefix: (B, H, W, 3) -> the integer core's entry codes
+    (FP edge conv, pre-entry float pools, entry quantizer)."""
     from ..core import integer_inference as ii
-    plan = layer_plan(cfg, fuse_pool)
+    plan = layer_plan(cfg)
     h = x
     for step in plan[:_split_plan(plan)]:
         if step[0] == "fp_conv":
-            # FP first conv (BN folded into w); same fp-in-fq-mode config
-            # as apply().
-            h = fql.fq_conv2d(ip["conv0"], h, QuantConfig(fq=qcfg.fq),
-                              padding="SAME", b_in=WEIGHT_BOUND)
+            h = _fp_first_conv(ip["conv0"], h, qcfg)
         else:  # pre-entry float pool
             h = -jax.lax.reduce_window(
                 -h, jnp.inf, jax.lax.min, (1, 2, 2, 1), (1, 2, 2, 1),
                 "VALID")
-    codes = ii.entry_codes(h, ip["entry"], qcfg, b_in=RELU_BOUND)
-    codes = int_core(ip, codes, qcfg, cfg, impl=impl, fuse_pool=fuse_pool,
-                     noise=noise, rng=rng, mac_chunks=mac_chunks)
-    h = ii.decode_output(codes, ip["s_out_last"], qcfg.bits_out)
-    h = fql.fq_conv2d(ip["head"], h, QuantConfig(), padding="SAME",
-                      b_in=RELU_BOUND)
-    return jnp.mean(h, axis=(1, 2))
+    return ii.entry_codes(h, ip["entry"], qcfg, b_in=RELU_BOUND)
+
+
+def int_exit(ip, codes, qcfg: QuantConfig):
+    """The float suffix: last core codes -> logits (decode, FP classifier
+    conv, global average pool)."""
+    from ..core import integer_inference as ii
+    return _fp_head(ip["head"],
+                    ii.decode_output(codes, ip["s_out_last"], qcfg.bits_out))
 
 
 def qat_apply(params, state, x, qcfg: QuantConfig, cfg: DarkNetConfig, *,
@@ -266,8 +290,7 @@ def qat_apply(params, state, x, qcfg: QuantConfig, cfg: DarkNetConfig, *,
     h, codes, s_prev, li = x, None, None, 0
     for step in plan:
         if step[0] == "fp_conv":
-            h = fql.fq_conv2d(params["conv0"], h, QuantConfig(fq=qcfg.fq),
-                              padding="SAME", b_in=WEIGHT_BOUND)
+            h = _fp_first_conv(params["conv0"], h, qcfg)
         elif step[0] == "pool":
             if codes is None:
                 h = ops.maxpool2d(h)  # pre-entry FP pool (differentiable)
@@ -281,9 +304,7 @@ def qat_apply(params, state, x, qcfg: QuantConfig, cfg: DarkNetConfig, *,
                                      mac_chunks=mac_chunks, impl=impl)
             s_prev = params[name]["s_out"]
             li += 1
-    h = fql.fq_conv2d(params["head"], h, QuantConfig(), padding="SAME",
-                      b_in=RELU_BOUND)
-    return jnp.mean(h, axis=(1, 2))
+    return _fp_head(params["head"], h)
 
 
 def int_serve_fn(ip, qcfg: QuantConfig, cfg: DarkNetConfig, **kw):

@@ -179,15 +179,43 @@ def int_apply(ip, x, qcfg: QuantConfig, cfg: KWSConfig, *, impl=None,
     applies the chunked-accumulation mitigation). The FP embedding and
     head stay clean — the noise model covers the analog conv core.
     """
-    from ..core import integer_inference as ii
-    h = fql.dense(ip["embed"], x)
-    h, _ = fql.batchnorm(ip["embed_bn"][0], ip["embed_bn"][1], h, train=False)
-    codes = ii.entry_codes(h, ip["entry"], qcfg, b_in=RELU_BOUND)
+    codes = int_entry(ip, x, qcfg)
     codes = int_core(ip, codes, qcfg, cfg, impl=impl, noise=noise, rng=rng,
                      mac_chunks=mac_chunks)
-    h = ii.decode_output(codes, ip["s_out_last"], qcfg.bits_out)
-    h = jnp.mean(h, axis=1)  # FP global average pool (paper §3.4)
-    return fql.dense(ip["head"], h)
+    return int_exit(ip, codes, qcfg)
+
+
+def _fp_embed(p, bn, bn_state, x):
+    """FP embedding at the edge precision (``fq_layers.edge_precision``),
+    then its eval-mode BN."""
+    with fql.edge_precision():
+        h = fql.dense(p, x)
+    h, _ = fql.batchnorm(bn, bn_state, h, train=False)
+    return h
+
+
+def _fp_head(p, h):
+    """FP global average pool (paper §3.4), then the FP head at the edge
+    precision."""
+    h = jnp.mean(h, axis=1)
+    with fql.edge_precision():
+        return fql.dense(p, h)
+
+
+def int_entry(ip, x, qcfg: QuantConfig):
+    """The float prefix: (B, T, n_mfcc) -> the integer core's entry codes
+    (FP embedding + BN, entry quantizer)."""
+    from ..core import integer_inference as ii
+    h = _fp_embed(ip["embed"], *ip["embed_bn"], x)
+    return ii.entry_codes(h, ip["entry"], qcfg, b_in=RELU_BOUND)
+
+
+def int_exit(ip, codes, qcfg: QuantConfig):
+    """The float suffix: last core codes -> logits (decode, global
+    average pool, FP head)."""
+    from ..core import integer_inference as ii
+    return _fp_head(ip["head"],
+                    ii.decode_output(codes, ip["s_out_last"], qcfg.bits_out))
 
 
 def qat_apply(params, state, x, qcfg: QuantConfig, cfg: KWSConfig, *,
@@ -204,9 +232,7 @@ def qat_apply(params, state, x, qcfg: QuantConfig, cfg: KWSConfig, *,
     """
     from ..core import deploy_qat as dq
     plan = layer_plan(cfg)
-    h = fql.dense(params["embed"], x)
-    h, _ = fql.batchnorm(params["embed_bn"], state["embed_bn"], h,
-                         train=False)
+    h = _fp_embed(params["embed"], params["embed_bn"], state["embed_bn"], x)
     rngs = _layer_rngs(rng, len(plan))
     codes, s_prev = None, None
     for (name, dil), r in zip(plan, rngs):
@@ -215,8 +241,7 @@ def qat_apply(params, state, x, qcfg: QuantConfig, cfg: KWSConfig, *,
                                  noise=noise, rng=r, mac_chunks=mac_chunks,
                                  impl=impl)
         s_prev = params[name]["s_out"]
-    h = jnp.mean(h, axis=1)  # FP global average pool (paper §3.4)
-    return fql.dense(params["head"], h)
+    return _fp_head(params["head"], h)
 
 
 def int_serve_fn(ip, qcfg: QuantConfig, cfg: KWSConfig, **kw):

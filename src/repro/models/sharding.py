@@ -68,31 +68,6 @@ def dp_size() -> int:
     return n
 
 
-def _tuple_axis_constraints_ok() -> bool:
-    """jax 0.4.37's CPU SPMD backend MISCOMPILES a combined-tuple-axis
-    ``with_sharding_constraint`` (e.g. P(("pod","data"), ...)) inside a
-    ``lax.scan`` body: shards of the combined axis come back permuted
-    ((pod,data)=(0,1) swapped with (1,0)), silently corrupting the batch
-    mid-network (caught by test_sharded_train_step_subprocess: sharded
-    loss 7.05 vs 7.20 single-device). Single-axis constraints are fine.
-    Constraints are layout hints — correctness may not depend on them —
-    so on the CPU backend (tests, dry-runs) multi-axis entries are
-    dropped instead; TPU/GPU keep them (the miscompile is CPU-specific).
-
-    ``REPRO_TUPLE_AXIS_CONSTRAINTS=keep|drop`` overrides the backend
-    gate: ``keep`` re-enables tuple-axis constraints on CPU (used by
-    tests/test_sharding_rules.py's version-gated probe, which re-runs
-    the miscompile repro and fails "workaround removable" once a jax
-    upgrade fixes it), ``drop`` forces the CPU behaviour everywhere.
-    """
-    force = os.environ.get("REPRO_TUPLE_AXIS_CONSTRAINTS")
-    if force == "keep":
-        return True
-    if force == "drop":
-        return False
-    return jax.default_backend() != "cpu"
-
-
 def constrain(x, *spec):
     """with_sharding_constraint(x, P(*spec)) if a mesh is active, else x.
 
@@ -107,14 +82,13 @@ def constrain(x, *spec):
         return x
     ba = batch_axes()
     used = set(ba)
-    keep_tuples = _tuple_axis_constraints_ok()
     expanded = []
     for a in spec:
         if a == "batch":
             if len(ba) == 1:
                 expanded.append(ba[0])
             else:
-                expanded.append(ba if keep_tuples else None)
+                expanded.append(ba)
         elif a in used:
             expanded.append(None)
         else:
@@ -129,11 +103,8 @@ def serving_constrain(x, mesh):
     The serving-mesh analog of the training batch constraint: big flush
     batches data-parallel-shard their rows across replica devices
     (``launch.mesh.make_serving_mesh``). Routed through :func:`constrain`
-    ON PURPOSE — serving exercises the same constraint path (and the same
-    tuple-axis workaround gate) as training, so the version-gated probe
-    in tests/test_sharding_rules.py covers both. The serving mesh is a
-    single axis, so the spec is always single-axis and the jax-0.4.37
-    tuple-axis miscompile cannot engage; a no-op in values either way.
+    on purpose, so serving and training share one constraint path; a
+    no-op in values.
     """
     with use_mesh(mesh, batch_axes=("replica",)):
         return constrain(x, "batch")

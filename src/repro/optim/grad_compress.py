@@ -25,23 +25,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.5 re-exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x: experimental only
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-
-import inspect
-
-# The replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; resolve the one this jax accepts.
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check=False):
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
+                      check_vma=check)
 
 
 def q8_encode(g) -> Tuple[jax.Array, jax.Array]:

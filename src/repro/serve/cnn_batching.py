@@ -88,7 +88,8 @@ percentiles over only the last ``wait_window`` samples per bucket (a
 second bounded deque), so fleet SLO checks see RECENT latency instead of
 lifetime-diluted values; ``inflight_age`` (dispatch-to-resolve ticks:
 n/mean/max, the stuck-result metric); and ``replicas``, a per-lane list
-of flushes/served/in-flight depth/peak/stuck/device. Dead buckets
+of flushes/served/in-flight depth/peak/stuck/device, plus the ids of
+the devices its results actually landed on (``out_devices``). Dead buckets
 (emptied queues) are garbage-collected after every tick/drain so bucket
 state stays bounded under high shape cardinality; wait histograms are
 kept (bounded per bucket, capped bucket count) so end-of-run stats
@@ -114,7 +115,6 @@ fleet trace; flush/resolve/swap events are tagged with the replica id.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
@@ -167,6 +167,8 @@ class ReplicaLane:
     served: int = 0
     stuck: int = 0
     inflight_peak: int = 0
+    # ids of the devices the lane's results actually landed on
+    out_devices: set = dataclasses.field(default_factory=set)
 
 
 def batch_bucket(n: int, max_batch: int) -> int:
@@ -194,7 +196,7 @@ class CNNBatcher:
     **Replica lanes.** ``n_replicas`` lanes share ``apply_fn``'s jitted
     step unless ``replica_apply_fns`` supplies one closure per lane (over
     ``replicate_stack`` device copies); ``replica_devices`` pins each
-    lane's dispatch to a device via ``jax.default_device``. See the
+    lane's dispatch to a device by committing its inputs there. See the
     module docstring for routing and the bit-exactness contract.
 
     **Noise canary tier.** ``noise_config`` (a ``core.noise.NoiseConfig``
@@ -430,12 +432,15 @@ class CNNBatcher:
                    key=lambda l: (len(l.inflight), l.flushes, l.rid))
 
     def _dispatch(self, lane: ReplicaLane, *args):
-        """Run the lane's jitted step under the lane's device placement
-        and the kernels' autotune replica scope (table misses recorded
-        at trace time attribute to the lane that compiled them)."""
-        ctx = jax.default_device(lane.device) if lane.device is not None \
-            else contextlib.nullcontext()
-        with ctx, fq_conv.replica_scope(lane.rid):
+        """Run the lane's jitted step on the lane's device and inside the
+        kernels' autotune replica scope (table misses recorded at trace
+        time attribute to the lane that compiled them). The inputs are
+        committed to the lane's device, so the computation runs there: a
+        ``jax.default_device`` scope alone would not move a step whose
+        closure holds arrays committed elsewhere."""
+        if lane.device is not None:
+            args = jax.device_put(args, lane.device)
+        with fq_conv.replica_scope(lane.rid):
             return lane.step(*args)
 
     def _flush(self, key: Tuple, reqs: List[CNNRequest]) -> int:
@@ -477,6 +482,9 @@ class CNNBatcher:
             dev = self._dispatch(lane, x, key_n)
         else:
             dev = self._dispatch(lane, x)
+        lane.out_devices.update(
+            d.id for leaf in jax.tree_util.tree_leaves(dev)
+            if isinstance(leaf, jax.Array) for d in leaf.devices())
         self._emit("flush", key=key, tick=self._tick_no, n=len(reqs),
                    slots=slots, generation=self.generation, stuck=stuck,
                    replica=lane.rid)
@@ -755,7 +763,7 @@ class CNNBatcher:
              "served": lane.served, "inflight": len(lane.inflight),
              "inflight_peak": lane.inflight_peak, "stuck": lane.stuck,
              "device": str(lane.device) if lane.device is not None
-             else None}
+             else None, "out_devices": sorted(lane.out_devices)}
             for lane in self._lanes]
         return d
 

@@ -47,7 +47,8 @@ import numpy as np
 
 from ..analysis import planlint
 from ..analysis.report import Report, Severity
-from ..core.integer_inference import replicate_stack, stack_digest
+from ..core.integer_inference import (ConvertedStack, replicate_stack,
+                                      stack_digest)
 from ..core.noise import NoiseConfig
 from .cnn_batching import CNNBatcher, CNNRequest
 from .faults import FaultPlan, FaultyDevice
@@ -173,11 +174,12 @@ class FleetRuntime:
         stacks clean) — violations raise :class:`FleetConfigError`.
 
         ``n_replicas`` > 1 serves the model on that many replica lanes
-        (docs/SERVING_MESH.md): placement round-robins over
-        ``launch.mesh.replica_devices`` and each lane gets its own apply
-        closure over a ``replicate_stack`` device copy (falling back to
-        one shared closure for opaque unit-test model objects that
-        ``device_put`` cannot place). Canary, retrain and hot-swap stay
+        (docs/SERVING_MESH.md): placement follows
+        ``batcher_kw["replica_devices"]`` or round-robins over
+        ``launch.mesh.replica_devices``, and each lane gets its own apply
+        closure over a ``replicate_stack`` device copy (opaque unit-test
+        model objects, not a ``ConvertedStack``, share one closure; a
+        stack that fails to place raises). Canary, retrain and hot-swap stay
         fleet-level decisions; swaps install replica-by-replica between
         flushes and surface as ``swap-replica`` trace events under the
         fleet's own ``swap``.
@@ -203,10 +205,11 @@ class FleetRuntime:
                    batcher=None, condition=condition,
                    n_replicas=n_replicas)
         m.window = deque(maxlen=slo.canary_window)
-        if n_replicas > 1 and "replica_devices" not in kw:
-            from ..launch import mesh as mesh_mod
-            m.devices = mesh_mod.replica_devices(n_replicas)
-            kw["replica_devices"] = m.devices
+        if n_replicas > 1:
+            if "replica_devices" not in kw:
+                from ..launch import mesh as mesh_mod
+                kw["replica_devices"] = mesh_mod.replica_devices(n_replicas)
+            m.devices = list(kw["replica_devices"])
         m.batcher = CNNBatcher(
             serve_builder(stack), device=self._device,
             on_event=lambda etype, kw, _m=m: self._bridge(_m, etype, kw),
@@ -224,16 +227,14 @@ class FleetRuntime:
 
     def _replica_fns(self, m: _Model):
         """Per-lane apply closures over placed stack copies, or None to
-        share one step across lanes. Opaque unit-test model objects (no
-        pytree registration / not device_put-able) fall back to sharing
-        — logically replicated, physically one closure."""
-        if m.n_replicas <= 1 or m.devices is None:
+        share one step across lanes. Only opaque unit-test model objects
+        (not a ``ConvertedStack``) share — logically replicated,
+        physically one closure; a real stack that fails to place raises."""
+        if m.n_replicas <= 1 or m.devices is None \
+                or not isinstance(m.stack, ConvertedStack):
             return None
-        try:
-            stacks = replicate_stack(m.stack, m.devices)
-        except Exception:  # noqa: BLE001 — toy stacks: share the closure
-            return None
-        return [m.serve_builder(s) for s in stacks]
+        return [m.serve_builder(s)
+                for s in replicate_stack(m.stack, m.devices)]
 
     @staticmethod
     def _nc_list(nc: Optional[NoiseConfig]):

@@ -19,6 +19,7 @@ Covered violation classes:
  13. weight codes out of range          (planlint/code-range)
  14. fused-pool bookkeeping break       (planlint/fused-pool)
  15. final=True mid-chain               (planlint/spec-mismatch)
+ 16. lane-illegal block on tpu           (kernellint/blockspec)
 """
 import json
 
@@ -31,7 +32,7 @@ from repro.analysis import intlint, kernellint, planlint, targets
 from repro.analysis.__main__ import main as cli_main
 from repro.analysis.intlint import TraceSpec
 from repro.analysis.kernellint import ConvShape
-from repro.analysis.report import Report
+from repro.analysis.report import Report, Severity
 from repro.core import integer_inference as ii
 
 pytestmark = pytest.mark.mutation
@@ -257,6 +258,25 @@ def test_vmem_blowout_caught():
         table={(3, 3, 1, "int8"): {"bho": 224, "bco": 64}},
         measured={(3, 3, 1, "int8")})
     assert_caught(r, "kernellint/vmem")
+
+
+@pytest.mark.parametrize("knobs", [{"bco": 64}, {"bc": 64}])
+def test_lane_illegal_block_caught_on_tpu(knobs):
+    """A channel block that is neither the whole extent nor a 128-lane
+    multiple is refused by Mosaic; on the tpu backend it is a lint error,
+    while an interpret-mode backend takes it."""
+    shape = ConvShape("mut/conv", ho=28, wo=28, cin=256, cout=256,
+                      kh=3, kw=3)
+    key = (3, 3, 1, "int8")
+    r = Report()
+    kernellint.lint_shapes([shape], r, backend="tpu", table={key: knobs},
+                           measured={key})
+    assert_caught(r, "kernellint/blockspec")
+    r = Report()
+    kernellint.lint_shapes([shape], r, backend="cpu", table={key: knobs},
+                           measured={key})
+    assert not [f for f in r.findings if f.check == "kernellint/blockspec"
+                and f.severity >= Severity.ERROR]
 
 
 def test_unmeasured_shape_warned():

@@ -253,6 +253,59 @@ def test_darknet_qat_forward_bit_identical(impl, fuse_pool, node_seed):
     np.testing.assert_array_equal(np.asarray(yi), np.asarray(yq))
 
 
+def _matmul_precisions(closed, w_shapes):
+    """shape -> precisions of every conv/dot in the jaxpr (nested jaxprs
+    included) whose weight operand has that shape."""
+    found = {s: [] for s in w_shapes}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("conv_general_dilated", "dot_general"):
+                shape = tuple(eqn.invars[1].aval.shape)
+                if shape in found:
+                    found[shape].append(eqn.params["precision"])
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(closed.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("which", ["kws", "darknet"])
+@pytest.mark.parametrize("path", ["int_apply", "qat_apply"])
+def test_edge_layers_run_at_edge_precision(which, path):
+    """Serving and deployment-in-the-loop retraining compute the float
+    edge layers at one precision (``fq_layers.EDGE_PRECISION``), so QAT
+    optimises the entry codes that serving produces. A TPU's default f32
+    matmul is one bf16 pass and moves entry codes across bin edges; CPU
+    computes every precision alike, so this reads the traced precision of
+    each edge layer's matmul."""
+    from repro.core import fq_layers as fql
+    cfg, params, state, ip = _kws() if which == "kws" else _darknet()
+    module = kws if which == "kws" else darknet
+    if which == "kws":
+        x = jnp.zeros((2, cfg.seq_len, cfg.n_mfcc))
+        edges = ("embed", "head")
+    else:
+        x = jnp.zeros((2, 16, 16, cfg.in_channels))
+        edges = ("conv0", "head")
+    if path == "int_apply":
+        closed = jax.make_jaxpr(
+            lambda x_: module.int_apply(ip, x_, QCFG, cfg))(x)
+    else:
+        closed = jax.make_jaxpr(
+            lambda x_: module.qat_apply(params, state, x_, QCFG, cfg))(x)
+    shapes = {e: tuple(params[e]["w"].shape) for e in edges}
+    found = _matmul_precisions(closed, set(shapes.values()))
+    want = (jax.lax.Precision(fql.EDGE_PRECISION),) * 2
+    for e, s in shapes.items():
+        assert found[s], f"{path}: no matmul reads the {e} weights"
+        assert all(p == want for p in found[s]), (path, e, found[s])
+
+
 def test_qat_forward_jit_parity(node_seed):
     """jit(qat_apply) == eager qat_apply == int_apply (the training step
     runs jitted; the contract must survive compilation)."""

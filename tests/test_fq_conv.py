@@ -122,10 +122,13 @@ def test_fused_1x1_and_5x5_kernels():
 
 
 def test_fused_int32_accumulation():
-    """Cin large enough that int8 accumulation would overflow."""
+    """Cin large enough that int8 accumulation would overflow.
+
+    Non-negative codes make every sum ~4e6 (> 2**20) whatever the random
+    stream, while 9*512*127*28 < 2**24 keeps the f32 reference exact."""
     k1, k2 = jax.random.split(jax.random.key(3))
-    a = _codes(k1, (1, 6, 6, 512), -127, 127)
-    w = _codes(k2, (9 * 512, 8), -127, 127)
+    a = _codes(k1, (1, 6, 6, 512), 0, 127)
+    w = _codes(k2, (9 * 512, 8), 0, 28)
     got = fq_conv2d(a, w, jnp.float32(1.0), kh=3, kw=3, padding=(1, 1),
                     epilogue="dequant", interpret=True)
     wf = w.reshape(3, 3, 512, 8).astype(jnp.float32)
@@ -148,6 +151,21 @@ def test_block_knobs_dont_change_codes():
         got = fq_conv2d(a, w, scale, kh=3, kw=3, padding=(1, 1), n_out=15,
                         bho=bho, bco=bco, bc=bc, interpret=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
+
+
+@pytest.mark.parametrize("stride,bho", [(1, 3), (1, 4), (2, 2)])
+def test_1x1_row_tiles_without_halo(stride, bho):
+    """1x1 taps without a pool read no halo: several row tiles (a ragged
+    last one included) come from a reshape, not a gather, same codes."""
+    k1, k2 = jax.random.split(jax.random.key(17 + stride))
+    a = _codes(k1, (2, 10, 9, 16), 0, 15)
+    w = _codes(k2, (16, 24), -7, 7)
+    scale = jnp.float32(0.02)
+    got = fq_conv2d(a, w, scale, kh=1, kw=1, stride=(stride, stride),
+                    n_out=15, bho=bho, interpret=True)
+    want = ops.fq_conv2d_int(a, w, scale, ksize=1, stride=stride, padding=0,
+                             n_out=15, lo=0, impl="im2col")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_pick_blocks_respects_divisibility():

@@ -331,3 +331,82 @@ def test_serving_mesh_subprocess_four_devices():
                              os.path.abspath(__file__))), timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "MESH_SUBPROCESS_OK" in out.stdout
+
+
+def test_fleet_replica_placement_failure_raises():
+    """A real ConvertedStack that cannot be placed on its replica devices
+    fails registration instead of silently sharing one closure."""
+    from conftest import trained_int_params
+    from repro.core.quant import QuantConfig
+    from repro.models import kws
+    from repro.serve.fleet import FleetRuntime
+    cfg = kws.KWSConfig.reduced()
+    qcfg = QuantConfig(2, 4, 4, fq=True)
+    _, _, ip = trained_int_params(kws, cfg, kws.conv_names(cfg), qcfg)
+    probe = np.zeros((1, cfg.seq_len, cfg.n_mfcc), np.float32)
+    fl = FleetRuntime()
+    with pytest.raises(ValueError, match="device_put"):
+        fl.register("kws", ip, lambda s: kws.int_serve_fn(s, qcfg, cfg),
+                    probe=probe, canary_seed=1, n_replicas=2,
+                    batcher_kw=dict(replica_devices=["bogus", "bogus"]))
+    assert fl.models == ()
+
+
+_LANE_DEVICES = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4")
+    import sys
+    import jax, numpy as np
+    assert len(jax.devices()) == 4, jax.devices()
+    sys.path.insert(0, ".")
+    from benchmarks.common import trained_int_params
+    from repro.core.quant import QuantConfig
+    from repro.models import kws
+    from repro.serve.cnn_batching import CNNBatcher, CNNRequest
+    from repro.serve.fleet import FleetRuntime
+
+    cfg = kws.KWSConfig.reduced()
+    qcfg = QuantConfig(2, 4, 4, fq=True)
+    _, _, ip = trained_int_params(kws, cfg, kws.conv_names(cfg), qcfg)
+    builder = lambda s: kws.int_serve_fn(s, qcfg, cfg)
+    rng = np.random.default_rng(0)
+    xs = rng.standard_normal((8, cfg.seq_len, cfg.n_mfcc)).astype(np.float32)
+    kw = dict(max_batch=2, max_wait_ticks=0, dispatch_ahead=True,
+              max_inflight=1)
+    m = FleetRuntime().register("kws", ip, builder, probe=xs[:2],
+                                canary_seed=3, n_replicas=4, batcher_kw=kw)
+    reqs = [CNNRequest(rid=i, x=x) for i, x in enumerate(xs)]
+    m.batcher.submit(reqs)
+    while m.batcher.outstanding():
+        m.batcher.tick()
+    lanes = m.batcher.stats["replicas"]
+    # every lane served, and each lane's results live on its own device
+    assert [l["out_devices"] for l in lanes] == [[d.id] for d in m.devices], \\
+        lanes
+    assert len({d.id for d in m.devices}) == 4
+
+    # one lane on device 0, same batch composition: bit-identical outputs
+    one = CNNBatcher(builder(ip), **kw)
+    ref = [CNNRequest(rid=i, x=x) for i, x in enumerate(xs)]
+    one.submit(ref)
+    while one.outstanding():
+        one.tick()
+    for a, b in zip(reqs, ref):
+        np.testing.assert_array_equal(a.out, b.out)
+    print("LANE_DEVICES_OK")
+""")
+
+
+def test_fleet_lanes_serve_on_their_own_devices():
+    """Four forced host devices: FleetRuntime's four replica lanes each
+    produce their results on their own device, bit-identical to a
+    one-lane run of the same flushes."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", _LANE_DEVICES],
+                         capture_output=True, text=True, env=env,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))), timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "LANE_DEVICES_OK" in out.stdout

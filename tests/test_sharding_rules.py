@@ -148,95 +148,3 @@ def test_sharded_train_step_subprocess():
     r = subprocess.run([sys.executable, "-c", _SUBPROCESS], env=env,
                        capture_output=True, text=True, timeout=600)
     assert "SUBPROCESS_OK" in r.stdout, r.stdout + r.stderr
-
-
-_TUPLE_AXIS_PROBE = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.environ["REPRO_TUPLE_AXIS_CONSTRAINTS"] = "keep"
-    import jax, jax.numpy as jnp
-    from repro.configs import get_arch
-    from repro.data import synthetic
-    from repro.launch import mesh as mesh_mod
-    from repro.models import transformer as T
-    from repro.optim import adam, schedules
-    from repro.train import trainer, elastic
-    from repro.models import sharding as shd
-
-    arch = get_arch("minitron-4b")
-    cfg = arch.smoke
-    mesh = mesh_mod.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    opt = adam.make(schedules.constant(1e-3))
-    step, (ps, os_, bs) = trainer.jit_train_step(
-        cfg, arch.qcfg, opt, trainer.TrainConfig(), mesh, arch.mode)
-    params = T.make_params(jax.random.key(0), cfg)
-    opt_state = opt.init(params)
-    batch = synthetic.lm_batch(jax.random.key(1), batch=8, seq_len=16,
-                               vocab=cfg.vocab)
-    with mesh, shd.use_mesh(mesh, ("pod", "data")):
-        params = elastic.reshard_with_specs(params, mesh, ps)
-        opt_state = elastic.reshard_with_specs(opt_state, mesh, os_)
-        _, _, m = step(params, opt_state, batch, jnp.int32(0))
-        l1 = float(m["loss"])
-    p_ref = T.make_params(jax.random.key(0), cfg)
-    s_ref = opt.init(p_ref)
-    step1 = jax.jit(trainer.make_train_step(cfg, arch.qcfg, opt,
-                                            trainer.TrainConfig()))
-    _, _, m_ref = step1(p_ref, s_ref, batch, jnp.int32(0))
-    print("TUPLE_AXIS_PROBE", l1, float(m_ref["loss"]))
-
-    # the serving mesh rides the same constrain() path (ISSUE 10): a
-    # single-axis replica constraint must stay a value no-op even with
-    # tuple-axis constraints force-kept — the workaround only ever
-    # drops COMBINED axes, so serving must be unaffected by either
-    # setting of the gate
-    smesh = mesh_mod.make_serving_mesh(2)
-    xb = jnp.arange(24.0).reshape(4, 6)
-    yb = jax.jit(lambda t: shd.serving_constrain(t, smesh))(xb)
-    assert bool(jnp.all(yb == xb)), "serving_constrain corrupted values"
-    print("SERVING_MESH_CONSTRAIN_OK")
-""")
-
-
-def test_tuple_axis_workaround_still_needed():
-    """Version-gated probe for the jax 0.4.37 CPU-SPMD miscompile that
-    ``sharding._tuple_axis_constraints_ok`` works around (combined-tuple-
-    axis with_sharding_constraint inside a lax.scan body permutes batch
-    shards).
-
-    Re-runs the original repro — the sharded train step with tuple-axis
-    constraints force-KEPT on CPU (``REPRO_TUPLE_AXIS_CONSTRAINTS=keep``)
-    — and requires it to still diverge from the single-device reference
-    (historically 7.05 vs 7.20). The day a jax upgrade makes this test
-    fail, the workaround is removable: delete the CPU gate in
-    ``_tuple_axis_constraints_ok`` and this probe together.
-
-    The probe also exercises the SERVING mesh through the same
-    ``constrain`` path (``sharding.serving_constrain`` over a 2-replica
-    mesh): its single-axis spec must stay a value no-op under the
-    force-kept gate, proving the workaround never needs to engage for
-    serving regardless of jax version.
-    """
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    r = subprocess.run([sys.executable, "-c", _TUPLE_AXIS_PROBE], env=env,
-                       capture_output=True, text=True, timeout=600)
-    lines = [ln for ln in r.stdout.splitlines()
-             if ln.startswith("TUPLE_AXIS_PROBE")]
-    assert lines, f"probe crashed:\n{r.stdout}\n{r.stderr}"
-    assert "SERVING_MESH_CONSTRAIN_OK" in r.stdout, \
-        f"serving-mesh constrain check failed:\n{r.stdout}\n{r.stderr}"
-    _, sharded, ref = lines[0].split()
-    diverged = abs(float(sharded) - float(ref)) > 1e-3
-    if jax.__version__ == "0.4.37":
-        assert diverged, (
-            "the tuple-axis miscompile repro no longer fires on the pinned "
-            f"jax 0.4.37 (sharded {sharded} == ref {ref}) — the probe lost "
-            "its trigger; re-derive it before trusting the workaround")
-    else:
-        assert diverged, (
-            f"workaround removable: jax {jax.__version__} compiles the "
-            f"tuple-axis constraint correctly (sharded {sharded} == ref "
-            f"{ref}); drop the CPU gate in "
-            "sharding._tuple_axis_constraints_ok and delete this probe")
