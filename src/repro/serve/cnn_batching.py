@@ -85,15 +85,37 @@ counters, see below) plus per-bucket
 ``wait_ticks`` percentiles — ``{bucket: {n, p50, p99, max}}`` where wait
 is submit-to-dispatch in ticks — and ``wait_ticks_recent``, the same
 percentiles over only the last ``wait_window`` samples per bucket (a
-second bounded deque), so fleet SLO checks see RECENT latency instead of
-lifetime-diluted values; ``inflight_age`` (dispatch-to-resolve ticks:
-n/mean/max, the stuck-result metric); and ``replicas``, a per-lane list
-of flushes/served/in-flight depth/peak/stuck/device, plus the ids of
-the devices its results actually landed on (``out_devices``). Dead buckets
-(emptied queues) are garbage-collected after every tick/drain so bucket
-state stays bounded under high shape cardinality; wait histograms are
-kept (bounded per bucket, capped bucket count) so end-of-run stats
-survive the GC.
+second bounded deque): recent latency, not diluted by a long history
+(``FleetRuntime.stats`` exports it per model); ``inflight_age``
+(dispatch-to-resolve ticks: n/mean/max, the stuck-result metric); and
+``replicas``, a per-lane list of flushes/served/in-flight depth/peak/
+stuck/device, plus the ids of the devices its results actually landed on
+(``out_devices``). Dead buckets (emptied queues) are garbage-collected
+after every tick/drain so bucket state stays bounded under high shape
+cardinality; wait histograms are kept (bounded per bucket, capped bucket
+count) so end-of-run stats survive the GC.
+
+Wall clock (``time.time_ns``, the clock of a JAX profiler trace's
+``profile_start_time``): every request carries ``submit_ns`` (its
+``submit`` call) and ``dispatch_ns`` (its flush starts packing);
+``wait_ms`` is ``dispatch_ns - submit_ns``, beside ``wait_ticks``. One
+clock read per ``submit`` call and per flush. With a
+``serve.spans.SpanLog`` set on ``spans`` (between ticks; None detaches
+it), each flush also records three host spans, linked by ``flush`` (the
+lifetime flush count at dispatch, kept on ``InflightFlush.flush``):
+
+  * ``serve.pack`` (``flush``, ``slots``, ``n``, ``bytes``): the padded
+    batch allocated and the rows copied in;
+  * ``serve.dispatch`` (``flush``, ``lane``): ``device_put`` on a pinned
+    lane and the jitted call, which on an unpinned lane enqueues the
+    argument's transfer (its host-side layout transpose runs on a
+    runtime thread, outside every span);
+  * ``serve.resolve`` (``flush``, ``lane``, ``age_ticks``): the host
+    blocked in ``device_get`` and the rows unpacked.
+
+With ``spans`` None a span site costs one attribute check. The log has
+no bound: attach it for a measured window and detach it after. Spans
+never go through ``on_event``, whose payloads replay bit for bit.
 
 Fault boundary (``device``, serve/faults.py): when a device boundary is
 installed, every flush dispatch first asks it for a fate. A failed
@@ -116,6 +138,7 @@ fleet trace; flush/resolve/swap events are tagged with the replica id.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -126,6 +149,7 @@ from ..core.noise import NoiseConfig
 from ..kernels import fq_conv
 from ..models import sharding
 from .shape_ladder import ShapeLadder
+from .spans import SpanLog
 
 
 @dataclasses.dataclass
@@ -141,6 +165,16 @@ class CNNRequest:
     finish_tick: int = -1                  # resolve/shed tick
     generation: int = -1                   # model generation that served it
     error: Optional[Dict] = None           # structured shed error, else None
+    # wall clock (time.time_ns) beside the tick fields:
+    submit_ns: int = -1
+    dispatch_ns: int = -1                  # its flush started packing
+
+    @property
+    def wait_ms(self) -> float:
+        """Submit to dispatch on the wall clock; -1.0 until dispatched."""
+        if self.dispatch_ns < 0:
+            return -1.0
+        return (self.dispatch_ns - self.submit_ns) / 1e6
 
 
 @dataclasses.dataclass
@@ -153,6 +187,7 @@ class InflightFlush:
     generation: int = 0              # model generation at dispatch
     ready_tick: int = 0              # dispatch_tick + 1 + injected stuck ticks
     replica: int = 0                 # lane that dispatched it
+    flush: int = -1                  # lifetime flush count at dispatch
 
 
 @dataclasses.dataclass
@@ -252,6 +287,7 @@ class CNNBatcher:
         self._mesh = mesh
         self._device = device          # serve.faults boundary (or None)
         self._on_event = on_event
+        self.spans: Optional[SpanLog] = None  # host span log, or no spans
         self.generation = 0            # bumped by every swap_apply_fn
         self._queues: Dict[Tuple, List[CNNRequest]] = {}
         self._age: Dict[Tuple, int] = {}
@@ -378,6 +414,7 @@ class CNNBatcher:
             x = np.asarray(r.x)
             xn = self.ladder.normalize(x) if self.ladder is not None else x
             prepared.append((r, x, xn))
+        now = time.time_ns()
         for r, x, xn in prepared:
             if self.ladder is not None:
                 if xn is None:
@@ -389,6 +426,7 @@ class CNNBatcher:
                     x = xn
             r.x_served = x
             r.submit_tick = self._tick_no
+            r.submit_ns = now
             key = (x.shape, x.dtype.str)
             self._queues.setdefault(key, []).append(r)
             self._age.setdefault(key, 0)
@@ -431,17 +469,22 @@ class CNNBatcher:
         return min(self._lanes,
                    key=lambda l: (len(l.inflight), l.flushes, l.rid))
 
-    def _dispatch(self, lane: ReplicaLane, *args):
+    def _dispatch(self, lane: ReplicaLane, flush: int, *args):
         """Run the lane's jitted step on the lane's device and inside the
         kernels' autotune replica scope (table misses recorded at trace
         time attribute to the lane that compiled them). The inputs are
         committed to the lane's device, so the computation runs there: a
         ``jax.default_device`` scope alone would not move a step whose
         closure holds arrays committed elsewhere."""
+        spans = self.spans
+        t = time.time_ns() if spans is not None else 0
         if lane.device is not None:
             args = jax.device_put(args, lane.device)
         with fq_conv.replica_scope(lane.rid):
-            return lane.step(*args)
+            out = lane.step(*args)
+        if spans is not None:
+            spans.record(t, "serve.dispatch", flush=flush, lane=lane.rid)
+        return out
 
     def _flush(self, key: Tuple, reqs: List[CNNRequest]) -> int:
         """Dispatch one padded batch to the least-loaded lane. Returns
@@ -461,11 +504,18 @@ class CNNBatcher:
             stuck = fate.stuck_ticks if self.dispatch_ahead else 0
         lane = self._route()
         slots = batch_bucket(len(reqs), self.max_batch)
+        fid = self._counters["flushes"]
+        now = time.time_ns()
         x = np.zeros((slots,) + shape, dtype=np.dtype(dtype))
         for i, r in enumerate(reqs):
             x[i] = r.x_served
             r.wait_ticks = self._tick_no - r.submit_tick
+            r.dispatch_ns = now
             r.generation = self.generation
+        spans = self.spans
+        if spans is not None:
+            spans.record(now, "serve.pack", flush=fid, slots=slots,
+                         n=len(reqs), bytes=x.nbytes)
         self._record_waits(key, reqs)
         self._signatures.add((key, slots))
         self._counters["flushes"] += 1
@@ -479,9 +529,9 @@ class CNNBatcher:
             key_n = jax.random.fold_in(self._noise_key,
                                        self._counters["noise_trials"])
             self._counters["noise_trials"] += 1
-            dev = self._dispatch(lane, x, key_n)
+            dev = self._dispatch(lane, fid, x, key_n)
         else:
-            dev = self._dispatch(lane, x)
+            dev = self._dispatch(lane, fid, x)
         lane.out_devices.update(
             d.id for leaf in jax.tree_util.tree_leaves(dev)
             if isinstance(leaf, jax.Array) for d in leaf.devices())
@@ -496,12 +546,12 @@ class CNNBatcher:
                 InflightFlush(key, reqs, dev, self._tick_no,
                               generation=self.generation,
                               ready_tick=self._tick_no + 1 + stuck,
-                              replica=lane.rid))
+                              replica=lane.rid, flush=fid))
             lane.inflight_peak = max(lane.inflight_peak, len(lane.inflight))
             self._counters["inflight_peak"] = max(
                 self._counters["inflight_peak"], self._inflight_flushes())
             return 0
-        n = self._finish(reqs, dev)
+        n = self._finish(reqs, dev, fid, lane.rid, 0)
         lane.served += n
         self._emit("resolve", key=key, tick=self._tick_no, reqs=reqs,
                    generation=self.generation, age=0, replica=lane.rid)
@@ -561,7 +611,10 @@ class CNNBatcher:
         self._shed(out, code="deadline", deadline_ticks=max_age_ticks)
         return out
 
-    def _finish(self, reqs: List[CNNRequest], dev) -> int:
+    def _finish(self, reqs: List[CNNRequest], dev, flush: int, lane: int,
+                age: int) -> int:
+        spans = self.spans
+        t = time.time_ns() if spans is not None else 0
         y = np.asarray(jax.device_get(dev))
         for i, r in enumerate(reqs):
             if r.done:
@@ -570,6 +623,9 @@ class CNNBatcher:
             r.finish_tick = self._tick_no
             r.done = True
         self._counters["served"] += len(reqs)
+        if spans is not None:
+            spans.record(t, "serve.resolve", flush=flush, lane=lane,
+                         age_ticks=age)
         return len(reqs)
 
     def _resolve_lane(self, lane: ReplicaLane) -> int:
@@ -580,7 +636,7 @@ class CNNBatcher:
             self._counters["inflight_age_max"], age)
         self._inflight_age_sum += age
         self._inflight_age_n += 1
-        n = self._finish(f.reqs, f.dev_out)
+        n = self._finish(f.reqs, f.dev_out, f.flush, f.replica, age)
         lane.served += n
         self._emit("resolve", key=f.key, tick=self._tick_no, reqs=f.reqs,
                    generation=f.generation, age=age, replica=f.replica)

@@ -408,3 +408,106 @@ def test_results_carry_generation_stamp():
     assert b.generation == 2 and b.stats["generation"] == 2
     assert all(r.generation == 0 for r in first)
     assert all(r.generation == 2 for r in second)
+
+
+def test_span_log_keeps_rows():
+    import time
+    from repro.serve.spans import SpanLog
+    log = SpanLog()
+    t = time.time_ns()
+    log.record(t, "a", k=1)
+    log.record(0, "b")
+    rows = log.rows()
+    assert [(r[0], r[2], r[3]) for r in rows] == [(t, "a", {"k": 1}),
+                                                  (0, "b", {})]
+    assert t <= rows[0][1] <= rows[1][1]
+    rows.clear()  # a copy
+    assert len(log.rows()) == 2
+
+
+_SPAN_MODES = {
+    "sync": dict(max_batch=4, max_wait_ticks=1),
+    "dispatch_ahead": dict(max_batch=4, max_wait_ticks=1,
+                           dispatch_ahead=True, max_inflight=2),
+    "two_lanes": dict(max_batch=4, max_wait_ticks=1, dispatch_ahead=True,
+                      max_inflight=1, n_replicas=2),
+}
+
+
+def _serve_mixed(mode, spans=None):
+    """A mixed trace (two shapes, partial batches) served tick by tick
+    with arrivals between ticks; returns requests, stats and the event
+    stream with each request named by its id."""
+    events = []
+
+    def on_event(etype, kw):
+        kw = dict(kw)
+        if "reqs" in kw:
+            kw["reqs"] = [r.rid for r in kw["reqs"]]
+        events.append((etype, kw))
+
+    b = CNNBatcher(_mark_fn, on_event=on_event, **_SPAN_MODES[mode])
+    b.spans = spans
+    rng = np.random.default_rng(13)
+    reqs = _reqs([(6, 3)] * 7 + [(4, 5)] * 3, rng)
+    for i in range(0, len(reqs), 3):
+        b.submit(reqs[i:i + 3])
+        b.tick()
+    for _ in range(6):
+        b.tick()
+    b.drain()
+    assert all(r.done and r.error is None for r in reqs)
+    return reqs, b.stats, events
+
+
+@pytest.mark.parametrize("mode", sorted(_SPAN_MODES))
+def test_flush_spans_link_by_flush_id(mode):
+    from repro.serve.spans import SpanLog
+    log = SpanLog()
+    reqs, stats, _ = _serve_mixed(mode, spans=log)
+    by_flush = {}
+    for s, e, name, attrs in log.rows():
+        assert s <= e
+        by_flush.setdefault(attrs["flush"], {}).setdefault(
+            name, []).append((s, e, attrs))
+    assert sorted(by_flush) == list(range(stats["flushes"]))
+    lanes = set()
+    for fid, spans in by_flush.items():
+        assert {k: len(v) for k, v in spans.items()} == {
+            "serve.pack": 1, "serve.dispatch": 1, "serve.resolve": 1}, fid
+        (p0, p1, pack), = spans["serve.pack"]
+        (d0, d1, disp), = spans["serve.dispatch"]
+        (r0, r1, res), = spans["serve.resolve"]
+        assert p1 <= d0 and d1 <= r0
+        assert 1 <= pack["n"] <= pack["slots"] <= 4
+        assert pack["bytes"] in (pack["slots"] * 6 * 3 * 4,
+                                 pack["slots"] * 4 * 5 * 4)  # float32 rows
+        assert disp["lane"] == res["lane"]
+        assert res["age_ticks"] >= 0 and (res["age_ticks"] == 0
+                                          or mode != "sync")
+        lanes.add(disp["lane"])
+    assert lanes == set(range(_SPAN_MODES[mode].get("n_replicas", 1)))
+    assert sum(v["serve.pack"][0][2]["n"] for v in by_flush.values()) == \
+        len(reqs)
+    resolved = {attrs["flush"]: s for s, _, name, attrs in log.rows()
+                if name == "serve.resolve"}
+    packed = {s: attrs["flush"] for s, _, name, attrs in log.rows()
+              if name == "serve.pack"}
+    for r in reqs:
+        assert 0 <= r.submit_ns <= r.dispatch_ns
+        assert r.wait_ms == (r.dispatch_ns - r.submit_ns) / 1e6 >= 0
+        # a request's flush starts packing at its dispatch stamp
+        assert r.dispatch_ns <= resolved[packed[r.dispatch_ns]]
+
+
+@pytest.mark.parametrize("mode", sorted(_SPAN_MODES))
+def test_span_log_changes_no_output_stat_or_event(mode):
+    from repro.serve.spans import SpanLog
+    bare, bare_stats, bare_events = _serve_mixed(mode)
+    spanned, stats, events = _serve_mixed(mode, spans=SpanLog())
+    for a, b in zip(bare, spanned):
+        np.testing.assert_array_equal(a.out, b.out)
+        assert (a.wait_ticks, a.finish_tick, a.generation) == \
+            (b.wait_ticks, b.finish_tick, b.generation)
+    assert stats == bare_stats
+    assert events == bare_events
