@@ -40,13 +40,23 @@ Scheduling model — a ``tick()`` is one host scheduling quantum:
     candidates age into the next tick.
   * **Dispatch-ahead** (``dispatch_ahead=True``): ``_flush`` dispatches
     and parks the un-fetched device result on an ``InflightFlush``; the
-    host keeps packing. A tick first resolves every in-flight result
-    dispatched on an earlier tick (the device ran during the inter-tick
-    interval; ``device_get`` on those is a fetch, not a stall), then
-    dispatches up to the free slots of the bounded in-flight window(s).
-    When every window is full, further candidates are back-pressured into
-    later ticks (``stats["window_waits"]`` counts the TICKS that ended
-    with candidates still waiting, not the candidates — a
+    host keeps packing. A tick resolves every in-flight result that is
+    ready (dispatched on an earlier tick: the device ran during the
+    inter-tick interval) and dispatches up to the window slots those
+    resolves free. Where a ready result resolves depends on its lane's
+    window. With a free slot, at the start of the tick, before any
+    pack. In a full window the ready results are *due*: each flush
+    routed to the lane first resolves the lane's oldest due result,
+    then packs, so the flush queued behind that result keeps the device
+    busy while the host packs (resolve k, flush k+2, resolve k+1, flush
+    k+3; ``stats["deferred_resolves"]``). Due results no flush displaced
+    resolve at the end of the tick. Routing, the budget and
+    ``inflight_peak`` count due results as resolved, so every request
+    dispatches and completes in the same tick, on the same lane, as if
+    all had resolved first. When every window is full, further
+    candidates are back-pressured into later ticks
+    (``stats["window_waits"]`` counts the TICKS that ended with
+    candidates still waiting, not the candidates — a
     ticks-under-pressure metric). Requests complete at *resolve* time,
     one tick after dispatch — the pipeline's latency cost for keeping
     the device fed.
@@ -80,6 +90,8 @@ Observability (``stats``): counters (``flushes``, ``served``,
 ``padded_rows``, ``ladder_hits``, ``ladder_normalized``,
 ``ladder_misses``, ``window_waits``, ``inflight_peak``,
 ``noise_trials`` — flushes dispatched under a noise canary config;
+``deferred_resolves`` — due results resolved just before the flush that
+took their slot;
 ``flush_faults``/``retries``/``stuck_flushes``/``shed`` — fault-layer
 counters, see below) plus per-bucket
 ``wait_ticks`` percentiles — ``{bucket: {n, p50, p99, max}}`` where wait
@@ -202,6 +214,10 @@ class ReplicaLane:
     served: int = 0
     stuck: int = 0
     inflight_peak: int = 0
+    # ready flushes left in a full window at the start of a tick: each
+    # resolves just before the flush that takes its slot, or at the end
+    # of the tick; depth counts them as resolved already
+    due: int = 0
     # ids of the devices the lane's results actually landed on
     out_devices: set = dataclasses.field(default_factory=set)
 
@@ -327,7 +343,7 @@ class CNNBatcher:
             "ladder_hits": 0, "ladder_normalized": 0, "ladder_misses": 0,
             "window_waits": 0, "inflight_peak": 0, "noise_trials": 0,
             "flush_faults": 0, "retries": 0, "stuck_flushes": 0, "shed": 0,
-            "inflight_age_max": 0,
+            "inflight_age_max": 0, "deferred_resolves": 0,
         }
 
     def _emit(self, etype: str, **kw):
@@ -450,11 +466,16 @@ class CNNBatcher:
         return sum(len(f.reqs) for lane in self._lanes
                    for f in lane.inflight)
 
+    @staticmethod
+    def _depth(lane: ReplicaLane) -> int:
+        """In-flight flushes on the lane, its due ready ones not counted."""
+        return len(lane.inflight) - lane.due
+
     def _inflight_flushes(self) -> int:
-        return sum(len(lane.inflight) for lane in self._lanes)
+        return sum(self._depth(lane) for lane in self._lanes)
 
     def _free_window(self) -> int:
-        return sum(max(0, self.max_inflight - len(lane.inflight))
+        return sum(max(0, self.max_inflight - self._depth(lane))
                    for lane in self._lanes)
 
     def outstanding(self) -> int:
@@ -464,10 +485,11 @@ class CNNBatcher:
 
     def _route(self) -> ReplicaLane:
         """Least-loaded replica lane, deterministically: min in-flight
-        depth, then fewest lifetime flushes (round-robin under sync
-        mode's always-empty windows), then lowest lane id."""
+        depth (due ready flushes not counted), then fewest lifetime
+        flushes (round-robin under sync mode's always-empty windows),
+        then lowest lane id."""
         return min(self._lanes,
-                   key=lambda l: (len(l.inflight), l.flushes, l.rid))
+                   key=lambda l: (self._depth(l), l.flushes, l.rid))
 
     def _dispatch(self, lane: ReplicaLane, flush: int, *args):
         """Run the lane's jitted step on the lane's device and inside the
@@ -488,8 +510,9 @@ class CNNBatcher:
 
     def _flush(self, key: Tuple, reqs: List[CNNRequest]) -> int:
         """Dispatch one padded batch to the least-loaded lane. Returns
-        #requests COMPLETED now (sync: all of them; dispatch-ahead: 0,
-        they resolve later).
+        #requests COMPLETED now (sync: all of them; dispatch-ahead: those
+        of the due flush it displaced from a full window, if any; its own
+        resolve later).
 
         With a fault boundary installed the dispatch can fail BEFORE
         reaching the device: the batch requeues at the front of its
@@ -503,6 +526,13 @@ class CNNBatcher:
                 return self._flush_fault(key, reqs)
             stuck = fate.stuck_ticks if self.dispatch_ahead else 0
         lane = self._route()
+        done = 0
+        if len(lane.inflight) >= self.max_inflight:
+            # full window: the slot's due flush resolves now, while the
+            # flush behind it keeps the device busy through the pack
+            lane.due -= 1
+            self._counters["deferred_resolves"] += 1
+            done = self._resolve_lane(lane)
         slots = batch_bucket(len(reqs), self.max_batch)
         fid = self._counters["flushes"]
         now = time.time_ns()
@@ -547,10 +577,10 @@ class CNNBatcher:
                               generation=self.generation,
                               ready_tick=self._tick_no + 1 + stuck,
                               replica=lane.rid, flush=fid))
-            lane.inflight_peak = max(lane.inflight_peak, len(lane.inflight))
+            lane.inflight_peak = max(lane.inflight_peak, self._depth(lane))
             self._counters["inflight_peak"] = max(
                 self._counters["inflight_peak"], self._inflight_flushes())
-            return 0
+            return done
         n = self._finish(reqs, dev, fid, lane.rid, 0)
         lane.served += n
         self._emit("resolve", key=key, tick=self._tick_no, reqs=reqs,
@@ -649,16 +679,27 @@ class CNNBatcher:
                    key=lambda l: (l.inflight[0].dispatch_tick, l.rid))
         return self._resolve_lane(lane)
 
+    def _ready_heads(self, lane: ReplicaLane) -> int:
+        """Leading in-flight flushes of the lane ready by this tick."""
+        n = 0
+        for f in lane.inflight:
+            if f.ready_tick > self._tick_no:
+                break
+            n += 1
+        return n
+
     def _resolve_older_than(self, tick: int) -> int:
         """Fetch in-flight results that are ready by ``tick`` (the device
         had the inter-tick interval to run them; a stuck result's
-        ``ready_tick`` was pushed out by the fault layer). Lanes merge in
-        (ready_tick, dispatch_tick, lane id) order — deterministic."""
+        ``ready_tick`` was pushed out by the fault layer), except on lanes
+        with due flushes. Lanes merge in (ready_tick, dispatch_tick, lane
+        id) order — deterministic."""
         n = 0
         while True:
             best = None
             for lane in self._lanes:
-                if lane.inflight and lane.inflight[0].ready_tick <= tick:
+                if lane.inflight and not lane.due \
+                        and lane.inflight[0].ready_tick <= tick:
                     rank = (lane.inflight[0].ready_tick,
                             lane.inflight[0].dispatch_tick, lane.rid)
                     if best is None or rank < best[0]:
@@ -701,10 +742,23 @@ class CNNBatcher:
         flush the ranked candidates within this tick's budget: one
         blocking flush (sync — the blocking fetch eats the quantum no
         matter how many lanes exist) or the free in-flight window slots
-        summed across every replica lane (dispatch-ahead — the budget
-        that scales with the replica count)."""
+        summed across every replica lane, counting each ready flush as a
+        free slot (dispatch-ahead — the budget that scales with the
+        replica count).
+
+        Dispatch-ahead resolves a ready flush at one of three points of
+        the tick, always in the tick it is ready. On a lane with a free
+        window slot, before any pack. On a full lane, the ready flushes
+        are due: each flush routed there first resolves the lane's
+        oldest, so the flush queued behind it keeps the device busy
+        while the host packs (resolve k, flush k+2, resolve k+1, flush
+        k+3; ``stats["deferred_resolves"]``). Due flushes no flush
+        displaced resolve at the end of the tick."""
         served = 0
         if self.dispatch_ahead:
+            for lane in self._lanes:
+                lane.due = self._ready_heads(lane) \
+                    if len(lane.inflight) >= self.max_inflight else 0
             served += self._resolve_older_than(self._tick_no)
             budget = self._free_window()
         else:
@@ -721,11 +775,15 @@ class CNNBatcher:
             self._queues[key] = q[take:]
             served += self._flush(key, q[:take])
             budget -= 1
-        if self.dispatch_ahead and self._candidate() is not None:
-            # a tick that ended with candidates still back-pressured
-            # behind the full window(s) (ticks-under-pressure, not a
-            # per-candidate count)
-            self._counters["window_waits"] += 1
+        if self.dispatch_ahead:
+            for lane in self._lanes:
+                lane.due = 0
+            served += self._resolve_older_than(self._tick_no)
+            if self._candidate() is not None:
+                # a tick that ended with candidates still back-pressured
+                # behind the full window(s) (ticks-under-pressure, not a
+                # per-candidate count)
+                self._counters["window_waits"] += 1
         self._gc_buckets()
         self._tick_no += 1
         return served

@@ -511,3 +511,158 @@ def test_span_log_changes_no_output_stat_or_event(mode):
             (b.wait_ticks, b.finish_tick, b.generation)
     assert stats == bare_stats
     assert events == bare_events
+
+
+# -- dispatch-ahead: where in a tick a ready flush resolves ------------------
+
+
+def _ordered(**kw):
+    """A batcher on _mark_fn that logs its flush/resolve events and spans
+    in one stream, in call order: (kind, flush id or None, lane)."""
+    from repro.serve.spans import SpanLog
+    stream = []
+
+    class Log(SpanLog):
+        __slots__ = ()
+
+        def record(self, start_ns, name, **attrs):
+            stream.append((name, attrs["flush"], attrs.get("lane")))
+
+    def on_event(etype, ev):
+        if etype in ("flush", "resolve"):
+            stream.append((etype, None, ev["replica"]))
+
+    b = CNNBatcher(_mark_fn, on_event=on_event, **kw)
+    b.spans = Log()
+    return b, stream
+
+
+def _one(rng, rid0, n):
+    return [CNNRequest(rid=rid0 + i,
+                       x=rng.standard_normal((2, 2)).astype(np.float32))
+            for i in range(n)]
+
+
+def test_full_window_resolves_just_before_the_flush_that_reuses_it():
+    """One lane, a full window of ready flushes, two flushes due: resolve
+    0, flush 2, resolve 1, flush 3 — never two flushes unfetched behind
+    an empty device queue."""
+    rng = np.random.default_rng(21)
+    b, stream = _ordered(max_batch=1, max_wait_ticks=0,
+                         dispatch_ahead=True, max_inflight=2)
+    first = _one(rng, 0, 2)
+    b.submit(first)
+    assert b.tick() == 0
+    del stream[:]
+    second = _one(rng, 2, 2)
+    b.submit(second)
+    assert b.tick() == 2
+    kinds = [(k, f) for k, f, _ in stream if k != "serve.dispatch"]
+    assert kinds == [
+        ("serve.resolve", 0), ("resolve", None),
+        ("serve.pack", 2), ("flush", None),
+        ("serve.resolve", 1), ("resolve", None),
+        ("serve.pack", 3), ("flush", None)]
+    st = b.stats
+    assert st["deferred_resolves"] == 2 and st["inflight_peak"] == 2
+    assert st["replicas"][0]["inflight_peak"] == 2
+    assert all(r.finish_tick == 1 for r in first)
+    assert all(r.wait_ticks == 0 and not r.done for r in second)
+    assert b.tick() == 2 and all(r.finish_tick == 2 for r in second)
+    assert b.stats["deferred_resolves"] == 2  # window not full: at start
+    assert b.stats["inflight_age"]["max"] == 1
+
+
+def test_free_slot_resolves_before_any_pack():
+    """A lane with a free slot fetches its ready flush at the start of
+    the tick, as under-loaded traffic always did; a full lane that no
+    flush reaches resolves its due flushes at the end of the tick."""
+    rng = np.random.default_rng(22)
+    b, stream = _ordered(max_batch=1, max_wait_ticks=0,
+                         dispatch_ahead=True, max_inflight=2)
+    b.submit(_one(rng, 0, 1))
+    b.tick()
+    del stream[:]
+    b.submit(_one(rng, 1, 2))
+    assert b.tick() == 1
+    kinds = [k for k, _, _ in stream if k != "serve.dispatch"]
+    assert kinds == ["serve.resolve", "resolve", "serve.pack", "flush",
+                     "serve.pack", "flush"]
+    assert b.stats["deferred_resolves"] == 0
+    del stream[:]
+    assert b.tick() == 2  # full window, nothing to flush: resolved late
+    assert [k for k, _, _ in stream] == ["serve.resolve", "resolve"] * 2
+    assert b.stats["deferred_resolves"] == 0 and b.outstanding() == 0
+
+
+def test_deferred_resolves_keep_routing_and_peak():
+    """Two lanes: lane 0 full of ready flushes, lane 1 one ready flush.
+    Routing and the in-flight peak count due flushes as resolved, so the
+    three flushes land on lanes 1, 0, 1 with a peak of three, as when
+    every ready flush resolved first; lane 0 displaces one due flush and
+    resolves the other at the end of the tick."""
+    rng = np.random.default_rng(23)
+    b, stream = _ordered(max_batch=1, max_wait_ticks=0,
+                         dispatch_ahead=True, max_inflight=2, n_replicas=2)
+    b.submit(_one(rng, 0, 3))
+    b.tick()
+    assert [l["inflight"] for l in b.stats["replicas"]] == [2, 1]
+    del stream[:]
+    b.submit(_one(rng, 3, 3))
+    assert b.tick() == 3
+    events = [(k, lane) for k, _, lane in stream if k in ("flush",
+                                                          "resolve")]
+    assert events == [("resolve", 1), ("flush", 1), ("resolve", 0),
+                      ("flush", 0), ("flush", 1), ("resolve", 0)]
+    st = b.stats
+    assert st["deferred_resolves"] == 1
+    assert st["inflight_peak"] == 3
+    assert [l["inflight_peak"] for l in st["replicas"]] == [2, 2]
+    assert [l["inflight"] for l in st["replicas"]] == [1, 2]
+
+
+def test_stuck_head_is_not_resolved_early():
+    """A flush the fault layer holds past its tick is not ready: its full
+    window takes no flush and nothing behind it resolves until it is."""
+    from repro.serve.faults import FlushFate
+
+    class StuckFirst:
+        max_retries, backoff_ticks = 3, 1
+
+        def __init__(self):
+            self.calls = 0
+
+        def flush_fate(self, *, tick=-1):
+            self.calls += 1
+            return FlushFate(False, 2 if self.calls == 1 else 0, -1)
+
+    rng = np.random.default_rng(24)
+    b, stream = _ordered(max_batch=1, max_wait_ticks=0,
+                         dispatch_ahead=True, max_inflight=2,
+                         device=StuckFirst())
+    first = _one(rng, 0, 2)
+    b.submit(first)
+    b.tick()                                  # tick 0: flush 0 stuck 2
+    b.submit(_one(rng, 2, 2))
+    del stream[:]
+    assert b.tick() == 0 and b.tick() == 0    # ticks 1, 2: head not ready
+    assert stream == [] and b.stats["window_waits"] == 2
+    assert b.tick() == 2                      # tick 3: both due, displaced
+    assert [r.finish_tick for r in first] == [3, 3]
+    assert b.stats["deferred_resolves"] == 2
+    assert b.stats["inflight_age"]["max"] == 3
+
+
+@pytest.mark.parametrize("n_replicas", [1, 2])
+def test_sync_mode_defers_no_resolve(n_replicas):
+    rng = np.random.default_rng(25)
+    b, stream = _ordered(max_batch=1, max_wait_ticks=0,
+                         n_replicas=n_replicas)
+    reqs = _one(rng, 0, 4)
+    b.submit(reqs)
+    for _ in range(4):
+        assert b.tick() == 1
+    assert [k for k, _, _ in stream if k in ("flush", "resolve")] == \
+        ["flush", "resolve"] * 4
+    assert b.stats["deferred_resolves"] == 0
+    assert [r.finish_tick for r in reqs] == [0, 1, 2, 3]

@@ -384,3 +384,159 @@ def test_backoff_delays_retry():
     assert np.array_equal(
         np.asarray(r.out),
         np.asarray(_gen_toy(0)(jnp.asarray(r.x_served)[None]))[0])
+
+
+# -- tick-level golden --------------------------------------------------------
+#
+# Seeded dispatch-ahead schedules on 1 and 4 lanes, with and without the
+# fault layer (failed and stuck flushes), with hot-swaps and deadline
+# sheds interleaved. ``fixtures/tick_golden.json`` holds, per request, the
+# flush that served it, its lane, ``wait_ticks``, ``finish_tick``,
+# ``generation``, shed code and an output digest, and the batcher's stats:
+# what the tick schedule decides. It was recorded with the tick order that
+# resolved every ready flush before the first pack. Any order inside a
+# tick must reproduce it exactly; regenerate it (``python
+# tests/test_serving_fuzz.py``) only for a change that means to move
+# requests between ticks, flushes or lanes.
+
+import hashlib
+import json
+import os
+
+from repro.serve.spans import SpanLog
+
+_GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "fixtures", "tick_golden.json")
+_GOLDEN_CASES = [(1, False), (1, True), (4, False), (4, True)]
+_GOLDEN_SEEDS = range(8)
+_GOLDEN_SYNC_SEEDS = range(4)  # sync mode, the same schedules
+
+
+class _ResolvedFlush(SpanLog):
+    """Keeps only the flush id of the latest ``serve.resolve`` span: it
+    is recorded just before the ``resolve`` event of the same flush."""
+    __slots__ = ("flush",)
+
+    def record(self, start_ns, name, **attrs):
+        if name == "serve.resolve":
+            self.flush = attrs["flush"]
+
+
+def _run_golden_schedule(seed, n_replicas, faults, *, dispatch_ahead=True,
+                         n_ops=36):
+    """A seeded schedule that keeps the windows full often: bursts of up
+    to six requests over three shapes, ticks, hot-swaps, sheds and the
+    odd drain. Returns (batcher, requests, {rid: (flush, lane)})."""
+    rng = np.random.default_rng((seed, n_replicas, faults))
+    device = None
+    if faults:
+        device = FaultyDevice(FaultPlan(
+            seed=seed, p_flush_fail=0.2, p_stuck=0.3, max_stuck_ticks=2,
+            p_canary_corrupt=0.0, max_retries=3, backoff_ticks=1))
+    served_by = {}
+    log = _ResolvedFlush()
+
+    def on_event(etype, kw):
+        if etype == "resolve":
+            for r in kw["reqs"]:
+                served_by[r.rid] = (log.flush, kw["replica"])
+        elif etype == "flush" and dispatch_ahead:
+            # a window slot is freed before a flush reuses it
+            assert len(b._lanes[kw["replica"]].inflight) < b.max_inflight
+
+    b = CNNBatcher(
+        _gen_toy(0), max_batch=int(rng.choice([2, 4])),
+        max_wait_ticks=int(rng.integers(0, 3)),
+        dispatch_ahead=dispatch_ahead,
+        max_inflight=int(rng.integers(1, 4)), step_fn=_gen_step(0),
+        device=device, n_replicas=n_replicas, on_event=on_event)
+    b.spans = log
+    reqs = []
+    for _ in range(n_ops):
+        op = rng.random()
+        if op < 0.5:
+            rs = [_mk_request(rng, len(reqs) + i, _SHAPES[:3])
+                  for i in range(int(rng.integers(1, 7)))]
+            b.submit(rs)
+            reqs.extend(rs)
+        elif op < 0.88:
+            b.tick()
+        elif op < 0.93:
+            b.shed_expired(int(rng.integers(3, 7)))
+        elif op < 0.97:
+            g = b.generation + 1
+            b.swap_apply_fn(_gen_toy(g), step_fn=_gen_step(g))
+        else:
+            b.drain()
+    for _ in range(800):
+        if not b.outstanding():
+            break
+        b.tick()
+    b.drain()
+    assert not b.outstanding(), f"seed {seed}: requests stuck"
+    return b, reqs, served_by
+
+
+def _golden_record(seed, n_replicas, faults, dispatch_ahead=True):
+    b, reqs, served_by = _run_golden_schedule(
+        seed, n_replicas, faults, dispatch_ahead=dispatch_ahead)
+    rows = []
+    for r in reqs:
+        flush, lane = served_by.get(r.rid, (None, None))
+        out = None if r.out is None else hashlib.blake2s(
+            np.ascontiguousarray(r.out).tobytes(), digest_size=8).hexdigest()
+        rows.append([r.rid, flush, lane, r.wait_ticks, r.finish_tick,
+                     r.generation, r.error and r.error["code"], out])
+    return {"requests": rows, "stats": b.stats}
+
+
+def _golden_key(seed, n_replicas, faults, dispatch_ahead=True):
+    mode = "ahead" if dispatch_ahead else "sync"
+    return f"{mode}/lanes{n_replicas}/faults{int(faults)}/seed{seed}"
+
+
+def _golden_all():
+    out = {}
+    for n, faults in _GOLDEN_CASES:
+        for seed in _GOLDEN_SEEDS:
+            out[_golden_key(seed, n, faults)] = _golden_record(
+                seed, n, faults)
+        for seed in _GOLDEN_SYNC_SEEDS:
+            out[_golden_key(seed, n, faults, False)] = _golden_record(
+                seed, n, faults, False)
+    return out
+
+
+def _load_golden():
+    with open(_GOLDEN) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("n_replicas,faults", _GOLDEN_CASES)
+@pytest.mark.parametrize("dispatch_ahead", [True, False])
+def test_tick_golden(n_replicas, faults, dispatch_ahead):
+    """Every request lands in the same flush, on the same lane, in the
+    same ticks and with the same answer as the recorded schedule, and
+    the stats match (the deferred-resolve counter is newer than the
+    fixture and checked on its own)."""
+    golden = _load_golden()
+    seeds = _GOLDEN_SEEDS if dispatch_ahead else _GOLDEN_SYNC_SEEDS
+    for seed in seeds:
+        key = _golden_key(seed, n_replicas, faults, dispatch_ahead)
+        got = json.loads(json.dumps(
+            _golden_record(seed, n_replicas, faults, dispatch_ahead)))
+        want = golden[key]
+        assert got["requests"] == want["requests"], key
+        deferred = got["stats"].pop("deferred_resolves")
+        assert got["stats"] == want["stats"], key
+        assert 0 <= deferred <= got["stats"]["flushes"], key
+        if not dispatch_ahead:
+            assert deferred == 0, key
+
+
+if __name__ == "__main__":
+    os.makedirs(os.path.dirname(_GOLDEN), exist_ok=True)
+    with open(_GOLDEN, "w") as f:
+        json.dump(_golden_all(), f, separators=(",", ":"))
+        f.write("\n")
+    print(f"wrote {_GOLDEN}")
