@@ -11,7 +11,7 @@ import sys
 
 from .report import Severity
 from .targets import DEFAULT_MAC_CHUNKS, darknet_target, kws_target, \
-    lm_target, run_analysis
+    lm_target, resnet_target, run_analysis
 
 
 def build_targets(names, *, reduced: bool):
@@ -28,8 +28,10 @@ def build_targets(names, *, reduced: bool):
                                       weight_format="auto"))
         elif n == "lm":
             out.append(lm_target(reduced=reduced))
+        elif n == "resnet":
+            out.append(resnet_target(reduced=reduced))
         else:
-            raise SystemExit(f"unknown stack {n!r} (kws/darknet/lm)")
+            raise SystemExit(f"unknown stack {n!r} (kws/darknet/lm/resnet)")
     return out
 
 
@@ -39,8 +41,10 @@ def main(argv=None) -> int:
         description="Static quantization-contract verifier for the "
                     "integer deployment path (intlint/planlint/kernellint)")
     ap.add_argument("--stack", action="append",
-                    choices=["kws", "darknet", "lm"],
-                    help="stack(s) to analyze (default: all)")
+                    choices=["kws", "darknet", "lm", "resnet"],
+                    help="stack(s) to analyze (default: kws, darknet, lm; "
+                    "resnet's strided 1x1 key has no measured CPU autotune "
+                    "entry)")
     ap.add_argument("--reduced", action="store_true",
                     help="analyze the reduced benchmark stacks (fast; CI "
                     "uses the full-size declared shapes)")

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from ..core import quant
 from ..core.noise import NoiseConfig
 from ..core.quant import QuantConfig, n_levels
-from ..models import darknet, fq_lm, kws
+from ..models import darknet, fq_lm, kws, resnet
 from . import intlint, kernellint, planlint
 from .intlint import TraceSpec
 from .kernellint import ConvShape
@@ -32,6 +32,8 @@ DEFAULT_NOISE = NoiseConfig(0.30, 0.30, 1.50)
 # paper's ImageNet letterbox (reduced stacks serve the benchmark size).
 DARKNET_INPUT = 224
 DARKNET_REDUCED_INPUT = 28
+RESNET_INPUT = 224
+RESNET_REDUCED_INPUT = 32
 
 _STANDIN_CACHE: Dict = {}
 
@@ -193,6 +195,51 @@ def darknet_target(qcfg: QuantConfig = DEFAULT_QCFG, *,
         shapes=darknet_conv_shapes(cfg, input_hw, batch, weight_format=fmt),
         plan=plan, n_pool_markers=sum(1 for l in cfg.layers if l == "M"),
         core_example=(codes,), weight_format=fmt)
+
+
+def resnet_conv_shapes(cfg, input_hw: int,
+                       weight_format: str = "int8") -> List[ConvShape]:
+    """Geometries of the integer convs of a residual net: every conv and
+    pool pads k // 2, so a stride-s step takes a side h to ceil(h / s)."""
+    sides = {}
+    shapes = []
+    for step in resnet.layer_plan(cfg):
+        if step.op in ("fp_conv", "pool"):
+            input_hw = -(-input_hw // step.stride)
+            sides["entry"] = input_hw
+            continue
+        h = -(-sides[step.src] // step.stride)
+        sides[step.name] = h
+        shapes.append(ConvShape(
+            name=f"resnet/{step.name}", ho=h, wo=h, cin=step.cin,
+            cout=step.cout, kh=step.ksize, kw=step.ksize,
+            stride=(step.stride, step.stride), weight_format=weight_format))
+    return shapes
+
+
+def resnet_target(qcfg: QuantConfig = DEFAULT_QCFG, *,
+                  reduced: bool = False, batch: int = 1) -> StackTarget:
+    """The integer residual core over its DAG: plain convs and fused
+    conv + add steps, stride 1 and 2, identity and projection shortcuts;
+    planlint re-proves the stream's scale ties (``handoff_edges``)."""
+    cfg = (resnet.ResNetConfig.reduced_bottleneck() if reduced
+           else resnet.ResNetConfig.resnet50())
+    input_hw = RESNET_REDUCED_INPUT if reduced else RESNET_INPUT
+    key = ("resnet", cfg, qcfg)
+    hit = _STANDIN_CACHE.get(key)
+    if hit is None:
+        params = resnet.standin_params(jax.random.key(0), cfg)
+        hit = (params, resnet.convert_int(params, {}, qcfg, cfg))
+        _STANDIN_CACHE[key] = hit
+    fq_params, stack = hit
+    shapes = resnet_conv_shapes(cfg, input_hw)
+    h = shapes[0].ho  # the first stage keeps the entry plane
+    codes = jnp.zeros((batch, h, h, cfg.widths[0]), jnp.int8)
+    return StackTarget(
+        name="resnet-reduced" if reduced else "resnet",
+        module=resnet, cfg=cfg, qcfg=qcfg, fq_params=fq_params, stack=stack,
+        chain=resnet.int_conv_names(cfg), shapes=shapes,
+        core_example=(codes,), handoff_edges=resnet.handoff_edges(cfg))
 
 
 def lm_target(qcfg: QuantConfig = DEFAULT_QCFG, *, reduced: bool = False,

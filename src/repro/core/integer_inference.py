@@ -622,6 +622,33 @@ def int_conv2d_pool(ip, codes, *, ksize: int, stride: int = 1,
                                                        "int8"))
 
 
+def int_conv2d_add(ip, codes, shortcut, *, ksize: int, stride: int = 1,
+                   padding: int = 0, dilation: int = 1, impl=None,
+                   noise: Optional[NoiseConfig] = None, rng=None,
+                   mac_chunks: int = 1):
+    """A residual block's last conv, its add and the ReLU after it as ONE
+    integer op: ``clip(branch + shortcut, 0, n_out)``, where ``branch``
+    is the conv's signed requant (``ip["lo"]`` = -n_out) onto the stream
+    scale that ``shortcut`` is already on (the DAG's hand-off edges tie
+    them).
+
+    Behind the kernels/ops dispatch point, as ``int_conv2d_pool``: the
+    fused path adds in the conv kernel's VMEM epilogue, the im2col path
+    composes the conv with :func:`int_residual_add` (the parity oracle).
+    ADC noise perturbs the conv's accumulator before the add on both
+    paths; the shortcut's codes are taken as they are.
+    """
+    w_codes, codes, sig, seed = noisy_operands(ip, codes, noise, rng)
+    return ops.fq_conv2d_add_int(codes, w_codes, ip["rescale"], shortcut,
+                                 ksize=ksize, stride=stride, padding=padding,
+                                 dilation=dilation, n_out=ip["n_out"],
+                                 lo=ip["lo"], impl=impl,
+                                 noise_sigma_acc=sig, noise_seed=seed,
+                                 mac_chunks=mac_chunks,
+                                 weight_format=ip.get("weight_format",
+                                                      "int8"))
+
+
 def int_maxpool2d(codes, *, window: int = 2, stride: int = 2):
     """2x2 maxpool directly on int8 codes (NHWC).
 

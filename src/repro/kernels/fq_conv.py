@@ -49,6 +49,12 @@ the kernel keeps one int32 accumulator per pool position and takes their
 elementwise max before requant: a pooled layer writes Ho*Wo/4 output
 bytes to HBM instead of Ho*Wo plus a second full read+write pooling pass.
 
+Fused residual add epilogue: with ``residual`` (a residual block's
+shortcut codes, on the same scale as the output) the epilogue adds the
+shortcut tile to the requantized signed branch codes and clips to
+[0, n_out], the add and the ReLU after it, before the int8 store. The
+branch's codes never reach HBM; the call is named ``fq_conv2d_add``.
+
 Block sizes: explicit knobs win, then ``AUTOTUNE_TABLE`` — measured-sweep
 winners persisted by ``benchmarks/autotune_conv.py`` to the checked-in
 ``autotune_table.json`` next to this file, loaded once on first use
@@ -397,16 +403,17 @@ def pick_blocks(*, ho: int, wo: int, cin: int, cout: int, kh: int, kw: int,
 
 
 def _kernel(scale_ref, x_ref, w_ref, *refs, n_cb: int, reads, t: Tiling,
-            epilogue: str, n_out: int, lo: int, noise: bool,
+            epilogue: str, n_out: int, lo: int, noise: bool, add: bool,
             mac_chunks: int, n_i: int, ho: int, wo: int, cout: int,
             weight_format: str):
     if noise:
-        sigma_ref, seed_ref, o_ref, acc_ref = refs
+        sigma_ref, seed_ref, *refs = refs
         # program_id reads hoisted out of the pl.when body (interpret
         # mode can't lower the primitive inside the cond).
         p, j = pl.program_id(0), pl.program_id(1)
-    else:
-        o_ref, acc_ref = refs
+    if add:
+        res_ref, *refs = refs
+    o_ref, acc_ref = refs
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -465,8 +472,15 @@ def _kernel(scale_ref, x_ref, w_ref, *refs, n_cb: int, reads, t: Tiling,
             # int_maxpool2d over requantized codes, but the unpooled tile
             # never reaches HBM.
             acc = a_k if acc is None else jnp.maximum(acc, a_k)
-        o_ref[0] = apply_epilogue(acc, scale_ref[0, 0], epilogue=epilogue,
-                                  n_out=n_out, lo=lo)
+        y = apply_epilogue(acc, scale_ref[0, 0], epilogue=epilogue,
+                           n_out=n_out, lo=lo)
+        if add:
+            # the residual add and the ReLU after it, on the stream's
+            # common scale: the branch's codes plus the shortcut's,
+            # clipped to [0, n_out] (integer_inference.int_residual_add)
+            y = jnp.clip(y.astype(jnp.int32) + res_ref[0].astype(jnp.int32),
+                         0, n_out).astype(jnp.int8)
+        o_ref[0] = y
 
 
 @functools.partial(
@@ -498,6 +512,7 @@ def fq_conv2d(
     mac_chunks: int = 1,
     interpret: bool = False,
     weight_format: str = "int8",
+    residual: Optional[jax.Array] = None,  # (B, Ho, Wo, Cout) int8 codes
 ) -> jax.Array:
     """Fused int8 NHWC conv2d with the requant/dequant epilogue in VMEM.
 
@@ -524,6 +539,14 @@ def fq_conv2d(
     dynamic range -> effective noise std / sqrt(K)). When
     ``noise_sigma_acc`` is None the compiled program is the unchanged
     clean kernel.
+
+    ``residual`` (shortcut codes shaped like the output) fuses a residual
+    add and the ReLU after it into the requant epilogue: each output tile
+    becomes ``clip(clip(round(acc * scale), lo, n_out) + residual, 0,
+    n_out)`` before the int8 store, the shortcut's tile read into VMEM
+    beside the accumulator's. The call is then named ``fq_conv2d_add``,
+    so a device trace tells it from the plain conv. Without it the
+    compiled program is the plain kernel.
     """
     assert epilogue in ("requant", "dequant")
     assert mac_chunks >= 1
@@ -557,6 +580,12 @@ def fq_conv2d(
         assert pool[0] >= 1 and pool[1] >= 1
         assert ho >= pool[0] and wo >= pool[1], \
             f"pool {pool} larger than conv output ({ho}, {wo})"
+    add = residual is not None
+    if add:
+        assert pool is None and epilogue == "requant", \
+            "the residual add fuses into an unpooled requant epilogue"
+        assert residual.shape == (b, ho, wo, cout), (residual.shape,
+                                                     (b, ho, wo, cout))
 
     bho, bco, bc = pick_blocks(ho=ho, wo=wo, cin=cin, cout=cout, kh=kh,
                                kw=kw, stride=stride, pool=pool,
@@ -605,24 +634,32 @@ def fq_conv2d(
         in_specs += [scalar_spec, scalar_spec]                   # sigma, seed
         inputs += [jnp.asarray(noise_sigma_acc, jnp.float32).reshape(1, 1),
                    jnp.asarray(noise_seed).astype(jnp.uint32).reshape(1, 1)]
+    out_spec = pl.BlockSpec((1, t.m, bco), lambda p, j, c: (p, 0, j))
+    if add:
+        # the shortcut in the output's own flat tiling: rows padded to the
+        # row tiles, columns to the pitch wq, channels to the out blocks
+        res = jnp.pad(residual, ((0, 0), (0, n_i * t.bhf - ho),
+                                 (0, t.wq - wo), (0, cout_pad - cout)))
+        in_specs.append(out_spec)
+        inputs.append(res.reshape(b * n_i, t.m, cout_pad))
     out_dtype = jnp.int8 if epilogue == "requant" else jnp.float32
     out = pl.pallas_call(
         functools.partial(
             _kernel, n_cb=n_cb, reads=_tap_reads(t, kh, kw, stride, dilation),
-            t=t, epilogue=epilogue, n_out=n_out, lo=lo, noise=noise,
+            t=t, epilogue=epilogue, n_out=n_out, lo=lo, noise=noise, add=add,
             mac_chunks=mac_chunks, n_i=n_i, ho=ho, wo=wo, cout=cout,
             weight_format=weight_format,
         ),
         grid=(b * n_i, n_j, n_cb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, t.m, bco), lambda p, j, c: (p, 0, j)),
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((b * n_i, t.m, cout_pad), out_dtype),
         scratch_shapes=[pltpu.VMEM((t.n_pos, t.m, bco), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="fq_conv2d",
+        name="fq_conv2d_add" if add else "fq_conv2d",
     )(*inputs)
     out = out.reshape(b, n_i * t.bhf, t.wq, cout_pad)
     return out[:, :h_out, :w_out, :cout]

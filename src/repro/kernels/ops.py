@@ -198,6 +198,37 @@ def fq_conv2d_int(a_codes, w_codes, scale, *, ksize: int, stride: int = 1,
     return y.reshape(b, ho, wo, -1)
 
 
+def fq_conv2d_add_int(a_codes, w_codes, scale, shortcut, *, ksize: int,
+                      n_out: int, lo: int, stride: int = 1, padding: int = 0,
+                      dilation: int = 1, impl=None, noise_sigma_acc=None,
+                      noise_seed=None, mac_chunks=1, weight_format="int8"):
+    """int8 conv2d + residual add + ReLU, fused where the backend can:
+    ``clip(clip(round(acc * scale), lo, n_out) + shortcut, 0, n_out)``,
+    ``lo`` the branch's signed floor (-n_out on a residual stream).
+
+    ``shortcut`` holds codes shaped like the conv output, on the same
+    scale (the residual stream's). "fused" adds them in the kernel's
+    epilogue (fq_conv.fq_conv2d ``residual=``); "im2col" composes the
+    plain conv with ``integer_inference.int_residual_add`` — the parity
+    oracle. ADC noise perturbs the accumulator before the add on both.
+    """
+    if conv_impl(impl) == "fused":
+        return fq_conv.fq_conv2d(
+            a_codes, w_codes, scale, kh=ksize, kw=ksize,
+            stride=(stride, stride), padding=(padding, padding),
+            dilation=(dilation, dilation), n_out=n_out, lo=lo,
+            noise_sigma_acc=noise_sigma_acc, noise_seed=noise_seed,
+            mac_chunks=mac_chunks, interpret=_interpret(),
+            weight_format=weight_format, residual=shortcut)
+    from ..core.integer_inference import int_residual_add
+    y = fq_conv2d_int(a_codes, w_codes, scale, ksize=ksize, stride=stride,
+                      padding=padding, dilation=dilation, n_out=n_out, lo=lo,
+                      impl="im2col", noise_sigma_acc=noise_sigma_acc,
+                      noise_seed=noise_seed, mac_chunks=mac_chunks,
+                      weight_format=weight_format)
+    return int_residual_add(y, shortcut, n_out=n_out, lo=0)
+
+
 def maxpool2d(y, *, window: int = 2, stride: int = 2):
     """VALID maxpool on int8 codes or f32 activations (NHWC).
 

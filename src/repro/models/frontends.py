@@ -119,6 +119,16 @@ def darknet_serving_ladder(cfg, sizes: Sequence) -> ShapeLadder:
     return ladder
 
 
+def resnet_serving_ladder(cfg, sizes: Sequence) -> ShapeLadder:
+    """Letterbox ladder for ``models.resnet`` requests ``(H, W, C)``.
+
+    ``sizes`` are (H, W) rungs (ints mean square planes); channels are
+    preserved exactly. Every conv and pool of a ResNet pads by k // 2, so
+    no rung collapses to an empty plane.
+    """
+    return ShapeLadder(LadderSpec("image", tuple(sizes), cfg.in_channels))
+
+
 def frontend_serving_ladder(cfg: FrontendConfig,
                             positions: Optional[Sequence[int]] = None
                             ) -> Optional[ShapeLadder]:

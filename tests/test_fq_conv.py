@@ -222,6 +222,52 @@ def test_fused_pool_bitexact_vs_unfused(stride, padding, hw):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# ---------------------------------------------------------------------------
+# fused residual add epilogue: clip(clip(round(acc * r), -n, n) + sc, 0, n)
+# in VMEM must be bit-exact with the conv + int_residual_add composition
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("ks,stride,hw,bho,bco", [
+    (1, 1, (14, 12), None, None),   # pitch wq == wo: no spare columns
+    (1, 1, (14, 12), 4, 8),         # ragged row tiles, two out blocks
+    (1, 2, (13, 11), None, None),   # strided 1x1: one of four phases read
+    (3, 1, (14, 12), None, None),   # wq - wo = 2 spare columns
+    (3, 1, (13, 11), 5, 8),         # spare columns, ragged rows, out blocks
+    (3, 2, (14, 12), None, None),   # wq - wo = 1 spare column
+    (3, 2, (13, 11), 3, None),
+])
+@pytest.mark.parametrize("noise", [False, True])
+def test_fused_add_bitexact_vs_oracle(ks, stride, hw, bho, bco, noise):
+    H, W = hw
+    B, Cin, Cout, n = 2, 6, 16, 7
+    k1, k2, k3 = jax.random.split(jax.random.key(7 * ks + stride + H), 3)
+    a = _codes(k1, (B, H, W, Cin), 0, n)
+    w = _codes(k2, (ks * ks * Cin, Cout), -1, 1)
+    pad = ks // 2
+    ho, wo = (H + 2 * pad - ks) // stride + 1, (W + 2 * pad - ks) // stride + 1
+    sc = _codes(k3, (B, ho, wo, Cout), 0, n)
+    scale = jnp.float32(0.21)
+    nkw = (dict(noise_sigma_acc=jnp.float32(2.0), noise_seed=jnp.uint32(9))
+           if noise else {})
+    want = ops.fq_conv2d_add_int(a, w, scale, sc, ksize=ks, stride=stride,
+                                 padding=pad, n_out=n, lo=-n, impl="im2col",
+                                 **nkw)
+    got = fq_conv2d(a, w, scale, kh=ks, kw=ks, stride=(stride, stride),
+                    padding=(pad, pad), n_out=n, lo=-n, bho=bho, bco=bco,
+                    residual=sc, interpret=True, **nkw)
+    assert got.dtype == want.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    g = np.asarray(got)
+    assert g.min() == 0 and g.max() == n  # both clips are exercised
+    # the add is the oracle's own: branch codes plus shortcut, clipped
+    branch = ops.fq_conv2d_int(a, w, scale, ksize=ks, stride=stride,
+                               padding=pad, n_out=n, lo=-n, impl="im2col",
+                               **nkw)
+    np.testing.assert_array_equal(
+        g, np.clip(np.asarray(branch, np.int32) + np.asarray(sc), 0, n))
+
+
 def test_fused_pool_matches_separate_maxpool_op():
     """fq_conv2d(pool=) == int_maxpool2d(fq_conv2d()) — the commuting-max
     claim, checked against the production code-domain pool itself."""

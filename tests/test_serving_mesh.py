@@ -294,11 +294,28 @@ _SUBPROCESS = textwrap.dedent("""
     b.submit(reqs)
     while b.outstanding():
         b.tick()
-    # replication path: bit-exact vs the unplaced single-device reference
+    # replication path against the unplaced single-device stack, one
+    # request at a time. The contract is the integer core's codes: each
+    # placed copy, at the padded batch of 4 a lane serves, gives every
+    # request the codes the reference gives it alone, bit for bit. The
+    # float head (decode, classifier, pooling) reduces in another order at
+    # batch 4 than at batch 1, which moves a logit by an ulp or two (3e-8
+    # at |logit| < 0.5 seen): compared to 1e-5 relative, 1e-6 absolute.
     ref_fn = kws.int_serve_fn(ip, qcfg, cfg)
     for r in reqs:
         want = np.asarray(ref_fn(jnp.asarray(r.x_served)[None]))[0]
-        np.testing.assert_array_equal(np.asarray(r.out), want)
+        np.testing.assert_allclose(np.asarray(r.out), want,
+                                   rtol=1e-5, atol=1e-6)
+    xs = np.stack([r.x_served for r in reqs])
+    want = [np.asarray(kws.int_core(ip, kws.int_entry(
+        ip, jnp.asarray(x)[None], qcfg), qcfg, cfg))[0] for x in xs]
+    for d, s in zip(devs, copies):
+        for i in range(0, len(xs), 4):
+            batch = jax.device_put(xs[i:i + 4], d)
+            got = kws.int_core(s, kws.int_entry(s, batch, qcfg), qcfg, cfg)
+            assert got.dtype == jnp.int8
+            for k, row in enumerate(np.asarray(got)):
+                np.testing.assert_array_equal(row, want[i + k])
     st = b.stats
     lanes_used = sum(1 for l in st["replicas"] if l["flushes"])
     assert lanes_used >= 2, st["replicas"]  # load actually spread
@@ -321,8 +338,9 @@ _SUBPROCESS = textwrap.dedent("""
 
 def test_serving_mesh_subprocess_four_devices():
     """End to end on four forced host devices: serving mesh + distinct
-    replica placement + per-replica closures over placed stack copies,
-    bit-exact vs the unplaced reference stack."""
+    replica placement + per-replica closures over placed stack copies;
+    integer core codes bit-exact vs the unplaced reference stack, logits
+    to a few ulp."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run([sys.executable, "-c", _SUBPROCESS],

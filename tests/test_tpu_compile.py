@@ -74,6 +74,37 @@ def test_fq_conv2d_compiles(one_chip, chip_blocks, hw, cin, cout, k, pool,
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("hw,cin,cout,k,stride,add", [
+    (56, 128, 128, 3, 2, False),   # ResNet-50 s1b0_c2: stride-2 3x3
+    (28, 256, 256, 3, 2, False),   # s2b0_c2
+    (14, 512, 512, 3, 2, False),   # s3b0_c2
+    (56, 256, 512, 1, 2, False),   # s1b0_sc: stride-2 1x1 projection
+    (28, 512, 1024, 1, 2, False),  # s2b0_sc
+    (14, 1024, 2048, 1, 2, False),  # s3b0_sc
+    (56, 64, 256, 1, 1, True),     # s0 blocks' last conv + residual add
+    (28, 128, 512, 1, 1, True),    # s1
+    (14, 256, 1024, 1, 1, True),   # s2
+    (7, 512, 2048, 1, 1, True),    # s3
+])
+def test_fq_conv2d_resnet50_compiles(one_chip, chip_blocks, hw, cin, cout, k,
+                                     stride, add):
+    """ResNet-50's strided convs and its fused conv + add, at batch 8."""
+    ho = -(-hw // stride)
+    shapes = [((8, hw, hw, cin), jnp.int8), ((k * k * cin, cout), jnp.int8),
+              ((), jnp.float32)]
+    if add:
+        shapes.append(((8, ho, ho, cout), jnp.int8))
+
+    def conv(a, w, s, *res):
+        return fq_conv.fq_conv2d(a, w, s, kh=k, kw=k, stride=(stride, stride),
+                                 padding=(k // 2, k // 2), lo=-7,
+                                 residual=res[0] if res else None)
+
+    hlo = _mosaic_hlo(conv, one_chip, *shapes)
+    assert "tpu_custom_call" in hlo
+    assert ("fq_conv2d_add" in hlo) == add
+
+
 @pytest.mark.parametrize("weight_format,noise", [
     ("int4", False), ("ternary", False), ("int8", True), ("ternary", True),
 ])
